@@ -193,4 +193,6 @@ def main(rows=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -164,4 +164,6 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true",
                     help="lower reps (<60 s CPU)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(quick=args.quick)

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.matserve import percentile as _percentile
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def bench_both(workload, *, rounds=7, max_batch=64, interpret=False):
@@ -684,6 +685,7 @@ def main(argv=None):
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.launch.matserve import make_workload
 

@@ -42,6 +42,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 
 def chain_bench(rows, sizes=(256, 512), power=64, reps=60):
     """Fused chain path vs the seed per-multiply path, same kernels.
@@ -157,6 +159,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default="BENCH_matpow.json",
                     help="machine-readable output path (name -> us_per_call)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from benchmarks import paper_tables
 

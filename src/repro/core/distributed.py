@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels import ops as _kops
 from repro.kernels.ops import PaddedChain
@@ -70,8 +69,8 @@ def matmul_2d_gather(a: jax.Array, b: jax.Array, mesh: Mesh, *,
         return jnp.matmul(a_row, b_col, preferred_element_type=jnp.float32
                           ).astype(a_blk.dtype)
 
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                     check_rep=False)(a, b)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)(a, b)
 
 
 def matmul_cannon(a: jax.Array, b: jax.Array, mesh: Mesh, *,
@@ -139,8 +138,8 @@ def matmul_cannon(a: jax.Array, b: jax.Array, mesh: Mesh, *,
         acc, _, _ = lax.fori_loop(0, n_steps, body, (acc, a_cur, b_cur))
         return acc.astype(a_blk.dtype)
 
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-                     check_rep=False)(a, b)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)(a, b)
 
 
 def _log2(x: int) -> int:

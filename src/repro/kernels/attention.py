@@ -21,13 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
 
@@ -136,18 +130,6 @@ def _flash_attention(q, k, v, *, causal, window, scale, interpret, block_q,
     n_kv = skv // block_k
     grid = (sq // block_q, n_kv)
 
-    kwargs = {}
-    if _HAVE_PLTPU and not interpret:
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "arbitrary"))
-
-    def scratch(shape, dtype):
-        if _HAVE_PLTPU:
-            return pltpu.VMEM(shape, dtype)
-        return pl.MemorySpace.ANY  # pragma: no cover
-
     kern = functools.partial(
         _attn_kernel, n_kv=n_kv, causal=causal, window=window, scale=scale,
         block_q=block_q, block_k=block_k, sq=sq, skv=skv)
@@ -163,10 +145,11 @@ def _flash_attention(q, k, v, *, causal, window, scale, interpret, block_q,
         out_specs=pl.BlockSpec((block_q, d), lambda qi, ki: (qi, 0)),
         out_shape=jax.ShapeDtypeStruct((sq, d), q.dtype),
         scratch_shapes=[
-            scratch((block_q, 1), jnp.float32),   # running max
-            scratch((block_q, 1), jnp.float32),   # running denom
-            scratch((block_q, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
+            pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(q, k, v)

@@ -27,13 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are unavailable when only CPU plugins exist
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["matmul_kernel", "matmul_pallas", "square_kernel",
            "square_panel_kernel", "square_pallas", "square_tier",
@@ -100,14 +94,6 @@ def matmul_pallas(
 
     grid = (m // block_m, n // block_n, n_k)
 
-    kwargs = {}
-    if _HAVE_PLTPU and not interpret:
-        # M/N tiles are independent; K must run sequentially (accumulator).
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     return pl.pallas_call(
         functools.partial(matmul_kernel, n_k=n_k, out_dtype=out_dtype),
         grid=grid,
@@ -117,17 +103,13 @@ def matmul_pallas(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_acc_scratch(block_m, block_n)],
+        # fp32 accumulator tile in VMEM (paper: per-work-group register tile).
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        # M/N tiles are independent; K must run sequentially (accumulator).
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(a, b)
-
-
-def _acc_scratch(block_m: int, block_n: int):
-    # fp32 accumulator tile in VMEM (paper: per-work-group register tile).
-    if _HAVE_PLTPU:
-        return pltpu.VMEM((block_m, block_n), jnp.float32)
-    return pl.MemorySpace.ANY  # pragma: no cover — interpret-only fallback
 
 
 # Largest whole-operand footprint the single-ref square kernel will stage in
@@ -257,15 +239,11 @@ def square_pallas(
             f"shape ({p},{p}) not divisible by blocks ({block_m},{block_n}); "
             "use ops.MatmulChain / ops.matmul for arbitrary shapes")
 
-    kwargs = {}
-    if _HAVE_PLTPU and not interpret:
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams")
-        # whole tier: both grid dims independent. panel tier: j must run
-        # sequentially innermost so each row panel is staged exactly once.
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel") if tier == "whole"
-            else ("parallel", "arbitrary"))
+    # whole tier: both grid dims independent. panel tier: j must run
+    # sequentially innermost so each row panel is staged exactly once.
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel") if tier == "whole"
+        else ("parallel", "arbitrary"))
 
     grid = (p // block_m, p // block_n)
     out_spec = pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))
@@ -279,8 +257,8 @@ def square_pallas(
             in_specs=[pl.BlockSpec((p, p), lambda i, j: (0, 0))],
             out_specs=out_spec,
             out_shape=out_shape,
+            compiler_params=params,
             interpret=interpret,
-            **kwargs,
         )(a)
 
     # Panel tier: the same array twice, viewed as row and column K-panels.
@@ -293,6 +271,6 @@ def square_pallas(
         ],
         out_specs=out_spec,
         out_shape=out_shape,
+        compiler_params=params,
         interpret=interpret,
-        **kwargs,
     )(a, a)

@@ -3,9 +3,10 @@
 ``matmul``      — arbitrary-shape tiled matmul: pads to block multiples,
                   strips the padding, vmaps over leading batch dims, and picks
                   block shapes that fit VMEM. On non-TPU backends it
-                  transparently falls back to the XLA dot (the Pallas TPU
-                  pipeline only lowers on TPU; ``interpret=True`` forces the
-                  kernel body on CPU for validation — used throughout tests/).
+                  takes the XLA dot (the Pallas TPU pipeline only lowers on
+                  TPU; ``interpret=True`` forces the kernel body on CPU for
+                  validation — used throughout tests/). See
+                  ``pallas_supported``.
 ``square``      — C = A @ A through the single-ref squaring kernel, same
                   pad/dispatch contract as ``matmul``.
 ``MatmulChain`` — fused chain executor for repeated-multiply workloads
@@ -42,7 +43,15 @@ __all__ = ["matmul", "square", "attention", "dense_matmul",
 
 
 def pallas_supported() -> bool:
-    """True when the default backend can lower a TPU Pallas pipeline."""
+    """True when the default backend can lower a TPU Pallas pipeline.
+
+    Where it is False (the CPU runs of the test suite) ``matmul``,
+    ``square``, ``MatmulChain`` and the Strassen leaves take the XLA dot
+    instead, unless ``interpret=True`` asks for the kernel bodies in the
+    Pallas interpreter. On a TPU this is True and that CPU route is never
+    taken: ``chip_smoke.py`` at the repo root fails unless the executables
+    the serving engine ran on the chip contain the Pallas kernels.
+    """
     return jax.default_backend() == "tpu"
 
 

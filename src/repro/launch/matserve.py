@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.admission import POLICIES, AdmissionControl, ShedError
 from repro.serve.matfn import ROUTES, MatFnEngine
 
@@ -225,6 +226,7 @@ def _verify(workload, results):
         return fns[key]
 
     worst = 0.0
+    rel_worst, compared, skipped = {}, 0, 0
     for (op, a, power, *rest), got in zip(workload, results):
         if isinstance(got, ShedError):     # shed requests have no answer
             continue
@@ -236,7 +238,54 @@ def _verify(workload, results):
             want = fn_for(op, power)(a)
         worst = max(worst, float(jnp.max(jnp.abs(
             got.astype(jnp.float32) - want.astype(jnp.float32)))))
+        ref = host_reference(op, a, power, rest[0] if rest else None)
+        if not in_range(ref, got.dtype):
+            skipped += 1
+            continue
+        compared += 1
+        rel_worst[op] = max(rel_worst.get(op, 0.0), rel_error(got, ref))
     print(f"[matserve] verify: max |batched - per-matrix| = {worst:.2e}")
+    per_op = " ".join(f"{op}={err:.2e}" for op, err in sorted(rel_worst.items()))
+    print(f"[matserve] verify: max rel err vs host f64 reference: "
+          f"{per_op or 'n/a'} ({compared} compared, {skipped} skipped: "
+          f"reference outside the dtype's normal range)")
+
+
+def host_reference(op, a, power, dists=None):
+    """The f64 answer computed on the host with numpy/scipy alone, so it
+    shares no code with the engine: ``A^p``, ``e^A``, the stationary
+    distribution (a linear solve of ``pi (P - I) = 0, sum(pi) = 1``), or
+    ``dists @ P^p``."""
+    import scipy.linalg
+
+    a = np.asarray(a, np.float64)
+    if op == "expm":
+        return scipy.linalg.expm(a)
+    if op == "matpow":
+        return np.linalg.matrix_power(a, power)
+    if dists is not None:
+        return np.asarray(dists, np.float64) @ np.linalg.matrix_power(a, power)
+    n = a.shape[0]
+    lhs = (a - np.eye(n)).T
+    lhs[-1] = 1.0
+    rhs = np.zeros(n)
+    rhs[-1] = 1.0
+    return np.linalg.solve(lhs, rhs)
+
+
+def in_range(ref, dtype) -> bool:
+    """True when the reference is finite and its largest entry lies where
+    ``dtype`` still holds it to full relative precision; an answer whose
+    reference underflows (or overflows) carries no information."""
+    fi = jnp.finfo(dtype)
+    peak = float(np.max(np.abs(ref))) if np.all(np.isfinite(ref)) else np.inf
+    return float(fi.tiny) / float(fi.eps) <= peak <= float(fi.max)
+
+
+def rel_error(got, ref) -> float:
+    """Relative Frobenius error ``||got - ref|| / ||ref||`` in f64."""
+    got = np.asarray(jnp.asarray(got, jnp.float32), np.float64)
+    return float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
 
 
 def percentile(xs, q):
@@ -443,6 +492,7 @@ def main(argv=None):
         ap.error("--markov-frac + --expm-frac must not exceed 1")
     if args.evolve_batch < 1:
         ap.error("--evolve-batch must be >= 1")
+    enable_compile_cache()
     sizes = [int(s) for s in args.sizes.split(",")]
     powers = [int(p) for p in args.powers.split(",")]
     dtypes = args.dtypes.split(",")

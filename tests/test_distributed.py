@@ -16,16 +16,13 @@ import pytest
 
 SRC = str(Path(__file__).resolve().parent.parent / "src")
 
-# jax.sharding.AxisType only exists on newer jax; older versions' make_mesh
-# has no axis_types kwarg and behaves as Auto. Prepended to every child.
-_MESH_COMPAT = """
+# jax.make_mesh defaults to Explicit axes; the code under test is written
+# for GSPMD-propagated (Auto) axes. Prepended to every child.
+_MESH_PRELUDE = """
 import jax
 def _make_mesh(shape, names):
-    try:
-        kinds = (jax.sharding.AxisType.Auto,) * len(names)
-    except AttributeError:
-        return jax.make_mesh(shape, names)
-    return jax.make_mesh(shape, names, axis_types=kinds)
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 """
 
 
@@ -33,7 +30,7 @@ def _run(body: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC
-    code = _MESH_COMPAT + textwrap.dedent(body)
+    code = _MESH_PRELUDE + textwrap.dedent(body)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, f"child failed:\n{proc.stdout}\n{proc.stderr}"
@@ -105,7 +102,6 @@ def test_sharded_forward_matches_single_device():
 def test_compressed_psum_and_error_feedback():
     _run("""
         import numpy as np, jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.parallel.collectives import compressed_psum, ef_compress
         mesh = _make_mesh((4,), ("data",))
@@ -113,8 +109,8 @@ def test_compressed_psum_and_error_feedback():
 
         def f(xs):
             return compressed_psum(xs, "data")
-        got = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                        check_rep=False)(x)
+        got = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                            out_specs=P("data"), check_vma=False)(x)
         want = jnp.sum(x, axis=0, keepdims=True)
         rel = float(jnp.abs(got[0] - want[0]).max() / jnp.abs(want).max())
         assert rel < 2e-2, rel
@@ -125,8 +121,8 @@ def test_compressed_psum_and_error_feedback():
         def g(xs, es):
             r, ne = ef_compress(xs, es, "data")
             return r, ne
-        gg = shard_map(g, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")), check_rep=False)
+        gg = jax.shard_map(g, mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")), check_vma=False)
         total = jnp.zeros((1024,))
         for i in range(16):
             r, err = gg(x, err)

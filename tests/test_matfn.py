@@ -375,3 +375,48 @@ class TestHeterogeneousDispatch:
             eng2.submit("matpow", a[i], power=3)
         eng2.flush()
         assert eng2.stats["routes"]["chain"] == 1
+
+
+class TestMatserveVerify:
+    """``matserve --verify`` compares every answer with a host f64
+    reference that shares no code with the engine."""
+
+    def test_host_reference_matches_numpy_and_scipy(self):
+        import scipy.linalg
+        from repro.launch.matserve import host_reference
+
+        rng = np.random.default_rng(0)
+        a = rng.standard_normal((12, 12)) * 0.2
+        np.testing.assert_allclose(host_reference("matpow", a, 5),
+                                   np.linalg.matrix_power(a, 5))
+        np.testing.assert_allclose(host_reference("expm", a, 1),
+                                   scipy.linalg.expm(a))
+        p = np.abs(a) + 0.1
+        p /= p.sum(1, keepdims=True)
+        pi = host_reference("markov", p, 1)
+        np.testing.assert_allclose(pi @ p, pi, atol=1e-12)
+        assert abs(pi.sum() - 1.0) < 1e-12
+        d = np.full((3, 12), 1.0 / 12)
+        np.testing.assert_allclose(host_reference("markov", p, 7, d),
+                                   d @ np.linalg.matrix_power(p, 7))
+
+    def test_verify_reports_f64_error_and_skips_underflow(self, capsys):
+        from repro.launch.matserve import _verify, make_workload, run_workload
+
+        work = make_workload(12, (8, 16), (3, 4000), expm_frac=0.25,
+                             seed=1, markov_frac=0.25)
+        results, _ = run_workload(MatFnEngine(), work)
+        _verify(work, results)
+        lines = capsys.readouterr().out.splitlines()
+        assert lines[0].startswith("[matserve] verify: max |batched")
+        f64 = lines[1]
+        assert "rel err vs host f64 reference" in f64
+        # A^4000 of a gaussian*0.4/sqrt(n) operand underflows f32: those
+        # requests are counted, not compared; the rest are within f32.
+        underflows = sum(1 for op, _a, p, *_ in work
+                         if op == "matpow" and p == 4000)
+        assert underflows > 0
+        assert f"{len(work) - underflows} compared, {underflows} skipped" \
+            in f64
+        for part in f64.split(": ", 2)[2].split(" (")[0].split():
+            assert float(part.split("=")[1]) < 1e-4
