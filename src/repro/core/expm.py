@@ -65,16 +65,24 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
     dtype = a.dtype
     compute = a.astype(jnp.float64 if dtype == jnp.float64 else jnp.float32)
 
-    norm = jnp.linalg.norm(compute, ord=1, axis=(-2, -1), keepdims=True)
-    # s = max(0, ceil(log2(norm / theta))) squarings, clipped to max_squarings.
-    s = jnp.maximum(0.0, jnp.ceil(jnp.log2(norm / _THETA13)))
-    s = jnp.minimum(s, float(max_squarings)).astype(jnp.int32)
-    scaled = compute / (2.0 ** s.astype(compute.dtype))
+    # Each phase runs under a named scope: the scope reaches every compiled
+    # instruction's op_name metadata, so a device trace can split an
+    # answer's time by phase whatever ops implement it. Scopes change
+    # metadata only, never the compiled program.
+    with jax.named_scope("expm.scale"):
+        norm = jnp.linalg.norm(compute, ord=1, axis=(-2, -1), keepdims=True)
+        # s = max(0, ceil(log2(norm / theta))) squarings, clipped to
+        # max_squarings.
+        s = jnp.maximum(0.0, jnp.ceil(jnp.log2(norm / _THETA13)))
+        s = jnp.minimum(s, float(max_squarings)).astype(jnp.int32)
+        scaled = compute / (2.0 ** s.astype(compute.dtype))
 
     ident = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=compute.dtype), compute.shape)
-    u, v = _pade13(scaled, ident)
-    # r = (v - u)^-1 (v + u)
-    r = jnp.linalg.solve(v - u, v + u)
+    with jax.named_scope("expm.pade"):
+        u, v = _pade13(scaled, ident)
+    with jax.named_scope("expm.solve"):
+        # r = (v - u)^-1 (v + u)
+        r = jnp.linalg.solve(v - u, v + u)
 
     # Squarings run inside the fori_loop (always traced) — donation never
     # fires, so skip the donate-enabled chain's defensive pad-time copy.
@@ -98,7 +106,8 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
         # corrupt its already-correct result. (i < s) broadcasts (..., 1, 1).
         return jnp.where(i < s, sq, r_cur)
 
-    r = lax.fori_loop(0, s_scalar, body, r)
+    with jax.named_scope("expm.square"):
+        r = lax.fori_loop(0, s_scalar, body, r)
     if chain is not None:
         r = chain.unpad(r)
     return r.astype(dtype)

@@ -194,7 +194,9 @@ def steady_state(p: jax.Array, *, tol: float = 1e-6,
 
     k0 = jnp.asarray(0, jnp.int32)
     r0 = jnp.asarray(jnp.inf, rdtype)
-    k, x, resid = lax.while_loop(cond, body, (k0, x0, r0))
+    # Named for the device trace (metadata only; the program is unchanged).
+    with jax.named_scope("markov.square"):
+        k, x, resid = lax.while_loop(cond, body, (k0, x0, r0))
 
     m = chain.unpad(x) if chain is not None else x
     pi = jnp.mean(m, axis=0)

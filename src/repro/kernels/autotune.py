@@ -98,7 +98,7 @@ __all__ = [
     "DEFAULT_MAX_DELAY_MS", "bucket_deadline_ms", "record_bucket_deadline",
     "DEFAULT_MARKOV_EVOLVE_THRESHOLD", "markov_evolve_threshold",
     "record_markov_evolve_threshold",
-    "cache_generation", "on_generation_bump",
+    "cache_generation",
 ]
 
 _ENV_VAR = "REPRO_AUTOTUNE_CACHE"
@@ -190,9 +190,6 @@ _MEM: dict = {}
 # Process-wide mutation counter for the cache (see ``cache_generation``).
 _GENERATION = 0
 
-# Listeners notified after every generation bump (see ``on_generation_bump``).
-_GENERATION_LISTENERS: list = []
-
 
 def cache_generation() -> int:
     """Monotone counter bumped on every cache mutation in this process.
@@ -207,36 +204,9 @@ def cache_generation() -> int:
     return _GENERATION
 
 
-def on_generation_bump(listener) -> "Callable[[], None]":
-    """Register ``listener(generation, reason)`` to fire after every cache
-    mutation; returns an unsubscribe callable.
-
-    The serving engine's telemetry uses this to annotate a live trace with
-    RETUNE events — a latency step in a Perfetto timeline lines up with
-    the exact ``record_*``/``load``/``clear`` that rerouted the engine.
-    Listeners run synchronously on the mutating thread and must be cheap
-    and non-raising (exceptions are swallowed: a broken observer must
-    never take down a retune).
-    """
-    _GENERATION_LISTENERS.append(listener)
-
-    def unsubscribe() -> None:
-        try:
-            _GENERATION_LISTENERS.remove(listener)
-        except ValueError:
-            pass
-
-    return unsubscribe
-
-
-def _bump_generation(reason: str = "mutation") -> None:
+def _bump_generation() -> None:
     global _GENERATION
     _GENERATION += 1
-    for listener in list(_GENERATION_LISTENERS):
-        try:
-            listener(_GENERATION, reason)
-        except Exception:   # noqa: BLE001 — observers must never break a retune
-            pass
 
 
 def cache_path() -> Path:
@@ -356,7 +326,7 @@ def load_cache(path: Optional[os.PathLike] = None) -> dict:
             warnings.warn(f"ignoring corrupted autotune cache {path}: {exc}")
             data = {}
     _MEM[memo_key] = data
-    _bump_generation("load")  # fresh disk read: memoized resolutions are stale
+    _bump_generation()  # fresh disk read: memoized resolutions are stale
     return data
 
 
@@ -371,7 +341,7 @@ def save_cache(cache: Optional[dict] = None,
     if cache is None:
         cache = _MEM.get(str(path), {})
     _MEM[str(path)] = cache
-    _bump_generation("save")
+    _bump_generation()
     try:
         path.parent.mkdir(parents=True, exist_ok=True)
         tmp = path.with_name(path.name + ".tmp")
@@ -385,7 +355,7 @@ def save_cache(cache: Optional[dict] = None,
 def clear_memory_cache() -> None:
     """Drop the in-process memo (tests; picks up external file edits)."""
     _MEM.clear()
-    _bump_generation("clear")
+    _bump_generation()
 
 
 def lookup(m: int, n: int, k: int, dtype=None,
@@ -435,7 +405,7 @@ def record(m: int, n: int, k: int, blocks: Sequence[int], dtype=None,
         "score": None if score is None else float(score),
         "measured": bool(measured),
     }
-    _bump_generation("record:matmul")
+    _bump_generation()
     if save:
         save_cache(cache)
 
@@ -466,7 +436,7 @@ def record_square_tiers(whole_limit: int, panel_limit: int, dtype=None,
         "tiers": [int(whole_limit), int(panel_limit)],
         "measured": bool(measured),
     }
-    _bump_generation("record:square_panel")
+    _bump_generation()
     if save:
         save_cache(cache)
 
@@ -506,7 +476,7 @@ def record_dispatch_thresholds(cpu_max_n: int, sharded_min_n: int, dtype=None,
         "thresholds": [int(cpu_max_n), int(sharded_min_n)],
         "measured": bool(measured),
     }
-    _bump_generation("record:dispatch")
+    _bump_generation()
     if save:
         save_cache(cache)
 
@@ -560,7 +530,7 @@ def record_fastmm(crossover_n: int, max_levels: int, leaf_blocks=None,
         "leaf_blocks": leaf_blocks,
         "measured": bool(measured),
     }
-    _bump_generation("record:fastmm")
+    _bump_generation()
     if save:
         save_cache(cache)
 
@@ -656,7 +626,7 @@ def record_bucket_deadline(op: str, n: int, max_delay_ms: float, dtype=None,
         "max_delay_ms": float(max_delay_ms),
         "measured": bool(measured),
     }
-    _bump_generation("record:deadline")
+    _bump_generation()
     if save:
         save_cache(cache)
 
@@ -708,7 +678,7 @@ def record_markov_evolve_threshold(threshold: float, dtype=None,
         "evolve_threshold": float(threshold),
         "measured": bool(measured),
     }
-    _bump_generation("record:markov")
+    _bump_generation()
     if save:
         save_cache(cache)
 

@@ -23,7 +23,11 @@ Two independent pieces, composable and individually cheap:
     ManualClock` daemon produces a fully deterministic timeline. A
     DISABLED tracer (the default, and :data:`NULL_TRACER`) short-circuits
     every record call on a single attribute check — tracing costs nothing
-    until it is switched on.
+    until it is switched on. An enabled tracer's lexical spans
+    (``span()``) are also ``jax.profiler.TraceAnnotation`` events that
+    carry their clock start as the stat ``t``: in a profiler trace each is
+    a clock anchor, so every engine span can be placed on the profiler's
+    timeline (see :data:`PROFILER_ONLY` and docs/observability.md).
   * :class:`Histogram` — fixed log-spaced buckets with exact counts:
     recording is O(1) (one ``log2`` + one index bump, no sample storage),
     merging is element-wise addition, and ``quantile(q)`` answers from the
@@ -52,15 +56,19 @@ import math
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "Histogram", "MetricsRegistry", "Tracer", "NULL_TRACER",
+    "Histogram", "MetricsRegistry", "Tracer", "NULL_TRACER", "NULL_SPAN",
     "DEFAULT_TRACE_CAPACITY", "SNAPSHOT_CHUNK", "SPAN_KINDS",
-    "REQUEST_OUTCOMES",
+    "PROFILER_ONLY", "REQUEST_OUTCOMES",
 ]
 
-#: Default ring-buffer bound for a Tracer (spans, not bytes). At ~7 spans
-#: per bucket plus 1 per request, 65536 covers several thousand buckets —
-#: hours of steady-state serving between exports.
+#: Default ring-buffer bound for a Tracer (records, not bytes). A request
+#: costs one record plus its share of its bucket's ~7 (``bucket.batch``,
+#: ``stream.queue``, ``stream.queue_depth``, ``bucket.assemble``,
+#: ``bucket.execute``, ``bucket.resolve``, ``scheduler.wait``): about 53,000
+#: records for 32,000 requests in 3,000 buckets.
 DEFAULT_TRACE_CAPACITY = 65536
 
 #: Spans copied per lock acquisition when exporting. A full-capacity ring
@@ -69,8 +77,10 @@ DEFAULT_TRACE_CAPACITY = 65536
 #: one slice and lets recorders interleave between chunks.
 SNAPSHOT_CHUNK = 2048
 
-#: The span/instant names the serving stack emits (the taxonomy tests and
-#: docs/observability.md enumerate; user code may add its own).
+#: The span/instant names the serving stack records in the ring (the
+#: taxonomy tests and docs/observability.md enumerate; user code may add
+#: its own). The ``bucket.*`` stage spans are lexical, so they are profiler
+#: annotations too; the others cross threads and live in the ring only.
 SPAN_KINDS = (
     "request",           # complete per-request lifecycle: submit -> terminal
     "bucket.batch",      # bucket open (first member) -> scheduler dispatch
@@ -84,7 +94,14 @@ SPAN_KINDS = (
     "retry",             # instant: executor attempt failed, retrying
     "straggler",         # instant: watchdog tripped on a flush
     "compile",           # instant: executable-cache miss (jit build)
-    "retune",            # instant: autotune cache generation bump
+)
+
+#: Lexical spans that go to the profiler only, never the ring: per-request
+#: (or per-bucket scheduler) points whose ring records would crowd out the
+#: bucket spans of a traced window.
+PROFILER_ONLY = (
+    "matfn.submit",        # MatFnEngine.submit, whole call
+    "scheduler.dispatch",  # the scheduler handing a popped bucket to a stream
 )
 
 #: Terminal outcomes a ``request`` span can carry — every admitted request
@@ -301,8 +318,13 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def tag(self, **tags) -> None:
+        pass
 
-_NULL_SPAN = _NullSpan()
+
+#: The shared no-op span a disabled tracer's ``span()`` returns; callers
+#: that guard on ``tracer.enabled`` themselves use it for the off path.
+NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -379,8 +401,7 @@ class Tracer:
 
     def instant(self, name: str, *, track: str = "main", at: Optional[float]
                 = None, **tags) -> None:
-        """Record a point event (shed / retry / straggler / compile /
-        retune)."""
+        """Record a point event (shed / retry / straggler / compile)."""
         if not self.enabled:
             return
         self._append({"name": name, "ph": "i",
@@ -399,27 +420,47 @@ class Tracer:
                       "args": dict(tags, value=value)})
 
     class _Span:
-        __slots__ = ("_tracer", "_name", "_track", "_tags", "_t0")
+        """One lexical span: a profiler annotation named like the span,
+        whose stat ``t`` is the span's clock start, and (``ring=True``) a
+        ring record on exit. ``start``/``end`` hold the clock times."""
 
-        def __init__(self, tracer, name, track, tags):
+        __slots__ = ("_tracer", "_name", "_track", "_tags", "_ring",
+                     "_annotation", "start", "end")
+
+        def __init__(self, tracer, name, track, ring, tags):
             self._tracer, self._name = tracer, name
-            self._track, self._tags = track, tags
+            self._track, self._ring, self._tags = track, ring, tags
+
+        def tag(self, **tags) -> None:
+            """Add tags known only inside the span (before it exits)."""
+            self._tags.update(tags)
 
         def __enter__(self):
-            self._t0 = self._tracer.now()
+            self.start = self._tracer.now()
+            self._annotation = TraceAnnotation(self._name, t=self.start)
+            self._annotation.__enter__()
             return self
 
         def __exit__(self, *exc):
-            self._tracer.add_span(self._name, self._t0, self._tracer.now(),
-                                  track=self._track, **self._tags)
+            self.end = self._tracer.now()
+            self._annotation.__exit__(*exc)
+            if self._ring:
+                self._tracer.add_span(self._name, self.start, self.end,
+                                      track=self._track, **self._tags)
             return False
 
-    def span(self, name: str, *, track: str = "main", **tags):
-        """Lexical span context manager (disabled tracers return a shared
-        no-op)."""
+    def span(self, name: str, *, track: str = "main", ring: bool = True,
+             **tags):
+        """Lexical span context manager: a ring record (unless
+        ``ring=False``) and a ``jax.profiler.TraceAnnotation`` of the same
+        name whose stat ``t`` is the span's start on this tracer's clock.
+        While a profiler session runs, those annotations anchor the clock
+        to the profiler's: trace ns = 1e9 * t + offset, one offset for
+        every span. Disabled tracers return the shared no-op
+        :data:`NULL_SPAN`."""
         if not self.enabled:
-            return _NULL_SPAN
-        return Tracer._Span(self, name, track, tags)
+            return NULL_SPAN
+        return Tracer._Span(self, name, track, ring, tags)
 
     # -- export ------------------------------------------------------------
     def __len__(self) -> int:

@@ -81,16 +81,17 @@ that pipeline as a service layer over the reproduction's chain executors:
     latency per lane (log-spaced buckets in a
     :class:`~repro.runtime.telemetry.MetricsRegistry`, exact over the
     whole run — no sample window), per-stage latency histograms
-    (queue / assemble / execute / resolve), and the watchdog's straggler
-    events. ``MatFnEngine(trace=True)`` additionally records every
-    request's LIFECYCLE as spans in a bounded ring buffer — submit ->
-    admit/shed -> bucket open -> flush trigger (fill/deadline/priority/
-    kick) -> stream queue -> execute (assemble/compile/device) ->
-    resolve/retry/shed — tagged by (op, n, dtype, lane, route, stream)
-    and exportable as Chrome trace-event JSON
-    (``engine.tracer.export(path)``; load in Perfetto). Near-zero cost
-    when disabled: every record site guards on one attribute. See
-    ``docs/observability.md``.
+    (queue / assemble / execute / resolve; submit and device as well
+    when tracing), and the watchdog's straggler events.
+    ``MatFnEngine(trace=True)`` additionally records every request's
+    LIFECYCLE as spans in a bounded ring buffer — submit -> admit/shed ->
+    bucket open -> flush trigger (fill/deadline/priority/kick) -> stream
+    queue -> execute (assemble/compile/device) -> resolve/retry/shed —
+    tagged by (op, n, dtype, lane, route, stream) and exportable as
+    Chrome trace-event JSON (``engine.tracer.export(path)``; load in
+    Perfetto); its host stages are also profiler annotations, anchored
+    to the engine clock. Near-zero cost when disabled: every record site
+    guards on one attribute. See ``docs/observability.md``.
 
 Flush policies and the injectable clock live in
 :mod:`repro.serve.scheduler`. Driver: ``python -m repro.launch.matserve``
@@ -106,6 +107,7 @@ import collections
 import dataclasses
 import functools
 import itertools
+import queue
 import threading
 import time
 from concurrent.futures import CancelledError, InvalidStateError
@@ -121,7 +123,8 @@ from repro.core.batched import batched_matpow
 from repro.core.expm import expm as _expm
 from repro.kernels import autotune
 from repro.runtime.fault import Watchdog, retry_step
-from repro.runtime.telemetry import NULL_TRACER, MetricsRegistry, Tracer
+from repro.runtime.telemetry import (NULL_SPAN, NULL_TRACER,
+                                     MetricsRegistry, Tracer)
 from repro.serve.admission import (LANES, AdmissionControl, PendingView,
                                    ShedError)
 from repro.serve.scheduler import (BucketView, FillOrDeadline, FlushPolicy,
@@ -441,6 +444,78 @@ def bucket_batch(b: int, max_batch: int = 64) -> int:
     return min(int(max_batch), 1 << (b - 1).bit_length())
 
 
+@dataclasses.dataclass
+class _Dispatched:
+    """One bucket chunk whose executable was called: its (padded) device
+    output, the member count, the route, and — when tracing — the span
+    tags of its stages."""
+    out: object
+    b: int
+    route: str
+    tags: Optional[dict]
+
+
+class _DeviceWatch:
+    """The ``stage=device`` histogram: bucket dispatch -> outputs ready.
+
+    One daemon thread blocks on a dispatched bucket's outputs, so no
+    stream worker waits on the device for it. It samples: it takes a
+    bucket only while it waits on none and at least ``SPACING_S`` of
+    dispatch time after the last one it took. A thread woken for every
+    bucket held a daemon near its knee back (traced ``mcmc`` on a TPU v5e
+    answered 2,000 instead of 3,200 requests a second); one sample per
+    20 ms wakes it at most 50 times a second whatever the bucket rate.
+    Started on first use, only by a tracing engine."""
+
+    SPACING_S = 0.02
+
+    def __init__(self, metrics: MetricsRegistry, now):
+        self._metrics = metrics
+        self._now = now
+        self._queue: queue.SimpleQueue = queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+        self._busy = False
+        self._last = -float("inf")
+
+    def watch(self, out, dispatched_at: float, route: str) -> None:
+        with self._lock:
+            if self._busy or dispatched_at - self._last < self.SPACING_S:
+                return
+            self._busy, self._last = True, dispatched_at
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._main, name="matfn-device-watch",
+                    daemon=True)
+                self._thread.start()
+        self._queue.put((out, dispatched_at, route))
+
+    def _main(self) -> None:
+        while True:
+            item = self._queue.get()
+            if item is None:
+                return
+            out, dispatched_at, route = item
+            try:
+                jax.block_until_ready(out)
+            except Exception:  # noqa: BLE001 — the bucket's futures carry it
+                pass
+            else:
+                self._metrics.record("stage", self._now() - dispatched_at,
+                                     stage="device", route=route)
+            finally:
+                with self._lock:
+                    self._busy = False
+
+    def close(self) -> None:
+        """Record the bucket being watched, then stop the thread."""
+        with self._lock:
+            thread, self._thread = self._thread, None
+        if thread is not None:
+            self._queue.put(None)
+            thread.join()
+
+
 class MatFnEngine:
     """Buckets pending matpow/expm requests and answers them batch-at-once.
 
@@ -531,7 +606,11 @@ class MatFnEngine:
         and the math not at all — the stream-identity CI gates run with
         it on. Histogram METRICS (``engine.metrics``) are always on:
         they replace the old per-lane latency deques behind ``stats()``
-        and cost one log2 + index bump per observation.
+        and cost one log2 + index bump per observation. Two stages are
+        recorded only when tracing, since they cost per request or per
+        bucket: ``submit`` (host seconds inside :meth:`submit`) and
+        ``device`` (bucket dispatch -> outputs ready, sampled by one
+        watcher thread).
     """
 
     def __init__(self, *, mesh=None, interpret: bool = False,
@@ -625,18 +704,9 @@ class MatFnEngine:
         else:
             raise TypeError(f"trace must be None, a bool, or a Tracer, "
                             f"got {type(trace).__name__}")
+        self._device_watch = _DeviceWatch(self.metrics, self._clock.now) \
+            if self.tracer.enabled else None
         self._rid = itertools.count()
-        # Retune visibility: autotune cache-generation bumps annotate the
-        # trace (a rerouted bucket is otherwise a mystery step in the
-        # timeline). Registered only when tracing — the listener registry
-        # is global, so disabled engines must not accumulate there.
-        self._unsub_retune = None
-        if self.tracer.enabled:
-            tracer = self.tracer
-            self._unsub_retune = autotune.on_generation_bump(
-                lambda gen, reason: tracer.instant(
-                    "retune", track="scheduler",
-                    generation=gen, reason=reason))
         self.stats = _Stats({
             "requests": 0, "buckets": 0, "compiles": 0,
             "cache_hits": 0, "padded_slots": 0,
@@ -694,6 +764,21 @@ class MatFnEngine:
         the raw dtype would split identical-math requests into separate
         buckets and executables.
         """
+        if not self.tracer.enabled:
+            return self._submit(op, operand, power, dists, priority, tenant)
+        # Traced: a profiler annotation and the ``stage=submit`` histogram,
+        # never a ring record (one per request would crowd the ring).
+        span = self.tracer.span("matfn.submit", ring=False)
+        try:
+            with span:
+                return self._submit(op, operand, power, dists, priority,
+                                    tenant)
+        finally:
+            self.metrics.record("stage", span.end - span.start,
+                                stage="submit")
+
+    def _submit(self, op: str, operand, power: int, dists, priority: str,
+                tenant: Optional[str]):
         if self._closed or self._closing:
             raise RuntimeError("engine is closed; no new requests")
         if priority not in LANES:
@@ -1110,9 +1195,9 @@ class MatFnEngine:
         with self._cv:
             pool = self._pool
 
-        def chunk_job(operands):
-            return lambda: jax.block_until_ready(
-                self._run_chunk(op, n, dtype.name, power, operands))
+        def run(operands):
+            return jax.block_until_ready(self._resolve_chunk(
+                self._run_chunk(op, n, dtype.name, power, operands)))
 
         count, jobs = 0, []
         for b in batches:
@@ -1120,10 +1205,10 @@ class MatFnEngine:
             if pool is not None:
                 stream = self._streams.stream_for(
                     self.route_for(n, b, dtype.name))
-                jobs.append(pool.call(stream, chunk_job(operands)))
+                jobs.append(pool.call(stream, functools.partial(run,
+                                                                operands)))
             else:
-                jax.block_until_ready(
-                    self._run_chunk(op, n, dtype.name, power, operands))
+                run(operands)
             count += 1
         for job in jobs:       # propagate compile errors to the caller
             job.result()
@@ -1131,65 +1216,76 @@ class MatFnEngine:
 
     # -- bucket execution core (shared by flush() and the daemon) ----------
     def _run_chunk(self, op: str, n: int, dtype: str, power: int,
-                   operands) -> tuple:
-        """Assemble, execute, and split ONE bucket chunk (<= max_batch).
+                   operands) -> _Dispatched:
+        """Assemble and execute ONE bucket chunk (<= max_batch); the caller
+        splits and delivers its rows with :meth:`_resolve_chunk`.
 
-        Returns the B per-request result rows. This is the single execution
-        core both the synchronous ``flush`` and the daemon scheduler run,
-        which is what keeps daemon answers bit-identical to synchronous
-        ones: same assembly, same executable cache, same routes.
+        This is the single execution core both the synchronous ``flush``
+        and the daemon scheduler run, which is what keeps daemon answers
+        bit-identical to synchronous ones: same assembly, same executable
+        cache, same routes.
 
-        Stage timing: the three phases — assemble (operand stack + pad +
-        executable lookup), execute (the jitted call; device-complete
-        only under ``profile=True``), resolve (row split) — feed the
-        ``stage`` histograms behind ``stats()["stages"]`` and, when
-        tracing, per-stage spans on the executing thread's track.
+        Stage timing: assemble (operand stack + pad + executable lookup)
+        and execute (the jitted call; device-complete only under
+        ``profile=True``) feed the ``stage`` histograms behind
+        ``stats()["stages"]`` and, when tracing, lexical spans on the
+        executing thread's track (ring records and profiler annotations),
+        plus the ``device`` stage: dispatch -> outputs ready.
         """
         b = len(operands)
         route = self.route_for(n, b, dtype, power)
         bpad = 1 if route == "sharded" else bucket_batch(b, self.max_batch)
+        tracer = self.tracer
+        if tracer.enabled:
+            track = threading.current_thread().name
+            tags = dict(op=op, n=n, dtype=dtype, route=route, batch=b,
+                        padded=bpad)
+            assemble = tracer.span("bucket.assemble", track=track, **tags)
+            execute = tracer.span("bucket.execute", track=track,
+                                  profiled=self.profile, **tags)
+        else:
+            tags = None
+            assemble = execute = NULL_SPAN
         clk = self._clock.now
         t0 = clk()
-        if _is_evolve(power):
-            # Evolve operands are (operand, dists) pairs (see
-            # MatFnRequest.payload); both stacks assemble in one dispatch.
-            stack = _assemble_pairs(tuple(m for m, _ in operands),
-                                    tuple(d for _, d in operands),
-                                    bpad=bpad)
-        else:
-            stack = _assemble(tuple(operands), bpad=bpad)
-        key, exe, fresh = self._executable(op, route, bpad, n, dtype, power)
+        with assemble:
+            if _is_evolve(power):
+                # Evolve operands are (operand, dists) pairs (see
+                # MatFnRequest.payload); both stacks assemble in one
+                # dispatch.
+                stack = _assemble_pairs(tuple(m for m, _ in operands),
+                                        tuple(d for _, d in operands),
+                                        bpad=bpad)
+            else:
+                stack = _assemble(tuple(operands), bpad=bpad)
+            key, exe, fresh = self._executable(op, route, bpad, n, dtype,
+                                               power)
+            assemble.tag(cold=fresh)
         t1 = clk()
-        if self.profile:
-            # Per-bucket wall time for the stats rows — blocks each bucket,
-            # so profiling serializes execution; leave it off to let
-            # buckets dispatch asynchronously. perf_counter, not the
-            # engine clock: this dt is honest device wall time even under
-            # a ManualClock test.
-            tp = time.perf_counter()
-            out = jax.block_until_ready(exe(stack))
-            dt = time.perf_counter() - tp
-        else:
-            out = exe(stack)
-            dt = None
+        with execute:
+            if self.profile:
+                # Per-bucket wall time for the stats rows — blocks each
+                # bucket, so profiling serializes execution; leave it off
+                # to let buckets dispatch asynchronously. perf_counter,
+                # not the engine clock: this dt is honest device wall
+                # time even under a ManualClock test.
+                tp = time.perf_counter()
+                out = jax.block_until_ready(exe(stack))
+                dt = time.perf_counter() - tp
+            else:
+                out = exe(stack)
+                dt = None
         t2 = clk()
-        rows = _split_rows(out, b=b)   # drops the filler slots too
-        t3 = clk()
         self.metrics.record("stage", t1 - t0, stage="assemble", route=route)
         self.metrics.record("stage", t2 - t1, stage="execute", route=route)
-        self.metrics.record("stage", t3 - t2, stage="resolve", route=route)
-        if self.tracer.enabled:
-            track = threading.current_thread().name
-            common = dict(op=op, n=n, dtype=dtype, route=route,
-                          batch=b, padded=bpad)
-            self.tracer.add_span("bucket.assemble", t0, t1, track=track,
-                                 cold=fresh, **common)
+        if tags is not None:
             if fresh:
-                self.tracer.instant("compile", at=t1, track=track, **common)
-            self.tracer.add_span("bucket.execute", t1, t2, track=track,
-                                 profiled=self.profile, **common)
-            self.tracer.add_span("bucket.resolve", t2, t3, track=track,
-                                 **common)
+                tracer.instant("compile", at=t1, track=track, **tags)
+            if self.profile:
+                self.metrics.record("stage", t2 - t1, stage="device",
+                                    route=route)
+            else:
+                self._device_watch.watch(out, t1, route)
         with self._stats_lock:
             self.stats["padded_slots"] += bpad - b
             self.stats["buckets"] += 1
@@ -1197,6 +1293,28 @@ class MatFnEngine:
             self.stats["last_flush"].append(
                 {"key": key, "requests": b, "padded_batch": bpad,
                  "route": route, "seconds": dt})
+        return _Dispatched(out, b, route, tags)
+
+    def _resolve_chunk(self, chunk: _Dispatched, deliver=None) -> tuple:
+        """The resolve stage of one chunk: split its B result rows off the
+        padded output (dropping the filler slots) and hand them to
+        ``deliver`` (the futures' resolution loop, or the synchronous
+        result slots). Returns the rows. Feeds ``stage=resolve`` and, when
+        tracing, the ``bucket.resolve`` span."""
+        if chunk.tags is not None:
+            span = self.tracer.span("bucket.resolve",
+                                    track=threading.current_thread().name,
+                                    **chunk.tags)
+        else:
+            span = NULL_SPAN
+        clk = self._clock.now
+        t0 = clk()
+        with span:
+            rows = _split_rows(chunk.out, b=chunk.b)
+            if deliver is not None:
+                deliver(rows)
+        self.metrics.record("stage", clk() - t0, stage="resolve",
+                            route=chunk.route)
         return rows
 
     # -- synchronous batch execution ---------------------------------------
@@ -1221,8 +1339,8 @@ class MatFnEngine:
         for (op, n, dtype, power), members in groups.items():
             for lo in range(0, len(members), self.max_batch):
                 chunk = members[lo:lo + self.max_batch]
-                rows = self._run_chunk(op, n, dtype, power,
-                                       [req.payload for _, req in chunk])
+                rows = self._resolve_chunk(self._run_chunk(
+                    op, n, dtype, power, [req.payload for _, req in chunk]))
                 for (idx, _), row in zip(chunk, rows):
                     results[idx] = row
         return results  # type: ignore[return-value]
@@ -1358,13 +1476,9 @@ class MatFnEngine:
         the thread keeps draining in the background — futures may still
         resolve) instead of silently reporting a completed drain.
         """
-        if self._unsub_retune is not None:
-            # Global listener registry — a closed engine must not keep
-            # annotating traces (idempotent; tolerates double close).
-            self._unsub_retune()
-            self._unsub_retune = None
         if self._daemon is None:
             self._closed = True
+            self._close_device_watch()
             return
         cancelled: List[_Bucket] = []
         cancel = False
@@ -1414,6 +1528,11 @@ class MatFnEngine:
                     f"execution streams still busy after {timeout}s; "
                     f"engine is closed to new submits, pending futures "
                     f"may yet resolve")
+        self._close_device_watch()
+
+    def _close_device_watch(self) -> None:
+        if self._device_watch is not None:
+            self._device_watch.close()
 
     # -- scheduler internals -----------------------------------------------
     def _any_due(self, now: float) -> bool:
@@ -1544,7 +1663,10 @@ class MatFnEngine:
                     finally:
                         self._waiting = False
             for bucket, trigger in due:
-                self._dispatch_bucket(bucket, trigger)
+                # A profiler annotation only: the scheduler's own work on a
+                # popped bucket (bucket.batch ends inside it).
+                with self.tracer.span("scheduler.dispatch", ring=False):
+                    self._dispatch_bucket(bucket, trigger)
 
     def _dispatch_bucket(self, bucket: _Bucket, trigger: str) -> None:
         """Hand one popped bucket to its route's execution stream.
@@ -1731,6 +1853,15 @@ class MatFnEngine:
                 return self._run_chunk(op, n, dtype, power,
                                        [req.payload for _, req in chunk])
 
+            def deliver(rows):
+                for (fut, _), row in zip(chunk, rows):
+                    self._resolve(fut, value=row)
+
+            def fail(exc):
+                err = BucketExecutionError(bucket.key, exc)
+                for fut, _ in chunk:
+                    self._resolve(fut, exc=err)
+
             def on_retry(attempt, exc):
                 self._evict_class_executables(bucket.key)
                 with self._stats_lock:
@@ -1743,13 +1874,11 @@ class MatFnEngine:
 
             t0 = time.perf_counter()
             try:
-                rows = retry_step(run_chunk, retries=self.retries,
-                                  backoff_s=self.retry_backoff_s,
-                                  on_retry=on_retry)
+                dispatched = retry_step(run_chunk, retries=self.retries,
+                                        backoff_s=self.retry_backoff_s,
+                                        on_retry=on_retry)
             except Exception as exc:
-                err = BucketExecutionError(bucket.key, exc)
-                for fut, _ in chunk:
-                    self._resolve(fut, exc=err)
+                fail(exc)
                 continue
             finally:
                 # Watchdog.observe serializes internally: concurrent
@@ -1768,8 +1897,14 @@ class MatFnEngine:
                         track=threading.current_thread().name,
                         key=str(bucket.key), lane=bucket.lane,
                         **event.as_tags())
-            for (fut, _), row in zip(chunk, rows):
-                self._resolve(fut, value=row)
+            try:
+                # The resolve stage (row split + the futures' resolution)
+                # runs once the watchdog has seen the flush, so a resolved
+                # future finds its bucket's accounting done.
+                self._resolve_chunk(dispatched, deliver)
+            except Exception as exc:
+                fail(exc)
+                continue
             with self._stats_lock:
                 lane_stats["flushed"] += len(chunk)
         with self._stats_lock:
@@ -1788,7 +1923,8 @@ class MatFnEngine:
         buckets, so quantiles carry ~9% relative error but never forget
         old samples the way the former deque window did). ``stages``
         breaks the pipeline down per stage (queue / assemble / execute /
-        resolve) across routes and streams; ``watchdog_events`` surfaces
+        resolve; submit / device when tracing) across routes and streams;
+        ``watchdog_events`` surfaces
         the straggler watchdog's structured event log; ``telemetry``
         reports the tracer's state. Taken under the engine lock; cheap
         enough to poll."""
@@ -1804,7 +1940,8 @@ class MatFnEngine:
                     else hist.quantile(0.95) * 1e3
                 lanes[lane] = row
             stages = {}
-            for stage in ("queue", "assemble", "execute", "resolve"):
+            for stage in ("submit", "queue", "assemble", "execute",
+                          "device", "resolve"):
                 hist = self.metrics.merged("stage", stage=stage)
                 if hist.count:
                     stages[stage] = hist.snapshot()

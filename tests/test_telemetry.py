@@ -20,7 +20,12 @@ Covers the observability acceptance criteria:
     ENGINE clock's epoch, so a ManualClock latency is the exact advanced
     interval (the epoch-mixing regression this PR fixed);
   * tracing stays off by default: the no-config engine uses the shared
-    ``NULL_TRACER`` and records nothing while serving real traffic.
+    ``NULL_TRACER`` and records nothing while serving real traffic;
+  * the profiler bridge: lexical spans write annotations whose stat ``t``
+    is their engine-clock start, the ``submit``/``device`` stages exist
+    only when tracing, ``bucket.resolve`` covers the futures' resolution,
+    and the named device phases reach op_name without changing the
+    compiled program.
 """
 
 import json
@@ -31,9 +36,9 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.runtime.telemetry import (NULL_TRACER, REQUEST_OUTCOMES,
-                                     SPAN_KINDS, Histogram, MetricsRegistry,
-                                     Tracer)
+from repro.runtime.telemetry import (NULL_TRACER, PROFILER_ONLY,
+                                     REQUEST_OUTCOMES, SPAN_KINDS, Histogram,
+                                     MetricsRegistry, Tracer)
 from repro.serve.admission import (AdmissionControl, RejectNewest,
                                    RejectOldest, ShedError)
 from repro.serve.matfn import BucketExecutionError, MatFnEngine
@@ -561,3 +566,154 @@ class TestEngineTracing:
         # independent pieces
         assert snap["lanes"]["bulk"]["p95_ms"] == 0.0
         eng.close()
+
+
+class TestProfilerBridge:
+    """Lexical spans are profiler annotations anchored to the engine
+    clock; the stages a tracing engine adds; the named device phases."""
+
+    def test_span_writes_annotation_with_clock_stat(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        tracer = Tracer(clock=lambda: 12.5)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracer.span("bucket.assemble", track="s") as sp:
+                sp.tag(cold=True)
+            with tracer.span("matfn.submit", ring=False):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        stats = {ev.name: dict(ev.stats)
+                 for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name in ("bucket.assemble", "matfn.submit")}
+        assert stats["bucket.assemble"]["t"] == pytest.approx(12.5)
+        assert stats["matfn.submit"]["t"] == pytest.approx(12.5)
+        # the ring holds the bridged span only, with its late tag
+        (s,) = tracer.spans()
+        assert s["name"] == "bucket.assemble"
+        assert s["args"] == {"cold": True}
+        assert (sp.start, sp.end) == (12.5, 12.5)
+
+    def test_submit_and_device_stages_only_when_tracing(self):
+        for trace in (True, False):
+            eng = MatFnEngine(max_batch=4, clock=ManualClock(),
+                              max_delay_ms=10.0, trace=trace)
+            eng.start()
+            futs = [eng.submit("matpow", _mat(8, seed=i), power=3)
+                    for i in range(8)]
+            eng.kick()      # flush whatever a racing scheduler left open
+            for f in futs:
+                f.result(timeout=TIMEOUT)
+            eng.close()
+            snap = eng.stats()
+            stages = snap["stages"]
+            if trace:
+                assert stages["submit"]["count"] == 8
+                # the watcher samples: the ManualClock never moves, so
+                # only the first bucket is ever 20 ms past the last one
+                assert stages["device"]["count"] == 1 < snap["buckets"]
+                names = {s["name"] for s in eng.tracer.spans()}
+                assert not names & set(PROFILER_ONLY)
+            else:
+                assert "submit" not in stages and "device" not in stages
+                assert eng.metrics.get("stage", stage="submit") is None
+            assert stages["resolve"]["count"] == snap["buckets"]
+
+    def test_profiled_engine_records_device_stage_inline(self):
+        eng = MatFnEngine(max_batch=4, profile=True, trace=True)
+        eng.submit("expm", _mat(8))
+        eng.flush()
+        assert eng.metrics.merged("stage", stage="device").count == 1
+        assert eng._device_watch._thread is None   # no watcher needed
+        eng.close()
+
+    def test_resolve_span_covers_the_futures_resolution(self):
+        # the real clock (resolution stamps must move), and a deadline
+        # no submit can reach: the four fill exactly one bucket
+        eng = MatFnEngine(max_batch=4, max_delay_ms=60_000.0, trace=True)
+        eng.start()
+        futs = [eng.submit("matpow", _mat(8, seed=i), power=3)
+                for i in range(4)]
+        for f in futs:
+            f.result(timeout=TIMEOUT)
+        eng.close()
+        (resolve,) = [s for s in eng.tracer.spans()
+                      if s["name"] == "bucket.resolve"]
+        for f in futs:
+            assert resolve["ts"] <= f.resolved_at \
+                <= resolve["ts"] + resolve["dur"]
+
+    def test_resolve_stage_failure_fails_the_chunk_not_the_stream(
+            self, monkeypatch):
+        from repro.serve import matfn
+        real = matfn._split_rows
+        calls = {"n": 0}
+
+        def split_once_broken(out, *, b):
+            calls["n"] += 1
+            if calls["n"] == 1:
+                raise RuntimeError("split failed")
+            return real(out, b=b)
+
+        monkeypatch.setattr(matfn, "_split_rows", split_once_broken)
+        eng = MatFnEngine(max_batch=2, clock=ManualClock(),
+                          max_delay_ms=10.0, trace=True)
+        eng.start()
+        first = [eng.submit("matpow", _mat(8, seed=i), power=3)
+                 for i in range(2)]
+        for f in first:
+            exc = f.exception(timeout=TIMEOUT)
+            assert isinstance(exc, BucketExecutionError)
+            assert "split failed" in str(exc.__cause__)
+        again = [eng.submit("matpow", _mat(8, seed=5 + i), power=3)
+                 for i in range(2)]
+        for f in again:                    # the stream kept serving
+            assert f.exception(timeout=TIMEOUT) is None
+        eng.close()
+        assert eng.stats()["lanes"]["bulk"]["flushed"] == 2
+
+    @pytest.mark.parametrize("fn, scopes", [
+        ("expm", ("expm.scale", "expm.pade", "expm.solve", "expm.square")),
+        ("steady_state", ("markov.square",)),
+    ])
+    def test_named_scopes_reach_op_name_and_change_no_code(
+            self, monkeypatch, fn, scopes):
+        import contextlib
+        import functools
+        import re
+
+        import jax
+        from jax import lax
+        from repro.core.expm import expm
+        from repro.core.markov import steady_state
+
+        member = functools.partial(
+            {"expm": expm, "steady_state": steady_state}[fn],
+            **({"validate": False} if fn == "steady_state" else {}))
+        x = jnp.zeros((4, 16, 16), jnp.float32)
+
+        def compiled():
+            # the engine's bucket program: lax.map over the members
+            return jax.jit(lambda x: lax.map(member, x)).lower(
+                x).compile().as_text()
+
+        def code(text):
+            # the program without its metadata: no op_name, and none of
+            # the source-location tables the module text starts with
+            text = re.sub(r", metadata=\{[^}]*\}", "", text)
+            return [line for line in text.splitlines()
+                    if not re.match(r"\d+ |(File|Function)\w+$|StackFrames$",
+                                    line)]
+
+        scoped = compiled()
+        for scope in scopes:
+            assert re.search(r'op_name="[^"]*/' + re.escape(scope) + "/",
+                             scoped), scope
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        plain = compiled()
+        assert not any(scope in plain for scope in scopes)
+        assert code(scoped) == code(plain)
