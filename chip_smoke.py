@@ -240,8 +240,9 @@ def pallas_kernels(engine, key) -> int:
     import jax
     _op, _route, bpad, n, dtype, _power = key
     exe = engine._executables[key]
-    spec = jax.ShapeDtypeStruct((bpad, n, n), dtype)
-    return exe.lower(spec).compile().as_text().count("tpu_custom_call")
+    spec = jax.ShapeDtypeStruct((n, n), dtype)   # one per padded slot
+    return exe.lower(*[spec] * bpad).compile().as_text().count(
+        "tpu_custom_call")
 
 
 # --- one chip: synchronous flush -------------------------------------------

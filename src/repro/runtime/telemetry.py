@@ -85,10 +85,10 @@ SPAN_KINDS = (
     "request",           # complete per-request lifecycle: submit -> terminal
     "bucket.batch",      # bucket open (first member) -> scheduler dispatch
     "stream.queue",      # stream dispatch -> execution start (the gap)
-    "bucket.assemble",   # operand stack + batch pad
-    "bucket.execute",    # executable call (dispatch, or device-complete
-                         # under profile=True)
-    "bucket.resolve",    # row split + future resolution
+    "bucket.assemble",   # executable lookup + argument list
+    "bucket.execute",    # the bucket's one program call (dispatch, or
+                         # device-complete under profile=True)
+    "bucket.resolve",    # host-side row pick + future resolution
     "scheduler.wait",    # scheduler sleep: deadline expiry vs wake
     "shed",              # instant: admission dropped a request
     "retry",             # instant: executor attempt failed, retrying

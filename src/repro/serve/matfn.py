@@ -288,7 +288,7 @@ class MatFnRequest:
     (B, n) stack of start distributions sharing ``operand`` as their
     transition matrix — its presence selects the evolve traffic class;
     without it a markov request is a steady-state query. ``dists`` must
-    match the operand dtype: the bucket assembler stacks per-dtype, and a
+    match the operand dtype: the bucket program stacks per-dtype, and a
     silent promotion would split identical-math requests across
     executables. The engine does NOT validate stochasticity — gate inputs
     with :func:`repro.core.markov.validate_stochastic` at the admission
@@ -334,7 +334,7 @@ class MatFnRequest:
 
     @property
     def payload(self):
-        """What the bucket assembler stacks for this request: the operand,
+        """What the bucket program stacks for this request: the operand,
         or the (operand, dists) pair for evolve requests."""
         return self.operand if self.dists is None \
             else (self.operand, self.dists)
@@ -386,52 +386,33 @@ class _Stats(dict):
         return self.snapshot()
 
 
-# One-dispatch bucket assembly: an eager ``jnp.stack`` over B small device
-# arrays costs one dispatch per operand (measured to dominate the flush),
-# and a host-side numpy round-trip costs two O(B n^2) copies; this jitted
-# assembler stacks + batch-pads in a single call (~4-5x faster than the
-# host path at every measured size). Filler slots are zero matrices.
-@functools.partial(jax.jit, static_argnames=("bpad",))
-def _assemble(operands, *, bpad: int):
-    stack = jnp.stack(operands)
-    b = stack.shape[0]
-    if bpad > b:
-        n = stack.shape[-1]
-        stack = jnp.concatenate(
-            [stack, jnp.zeros((bpad - b, n, n), stack.dtype)])
-    return stack
-
-
-# Evolve-bucket twin of ``_assemble``: stacks each request's (operand,
-# dists) pair into a ((bpad, n, n), (bpad, B, n)) pair in one dispatch.
-# Filler slots are zero matrices/stacks, same as ``_assemble``.
-@functools.partial(jax.jit, static_argnames=("bpad",))
-def _assemble_pairs(mats, dists, *, bpad: int):
-    mstack = jnp.stack(mats)
-    dstack = jnp.stack(dists)
-    b = mstack.shape[0]
-    if bpad > b:
-        n = mstack.shape[-1]
-        mstack = jnp.concatenate(
-            [mstack, jnp.zeros((bpad - b, n, n), mstack.dtype)])
-        dstack = jnp.concatenate(
-            [dstack, jnp.zeros((bpad - b,) + dstack.shape[1:],
-                               dstack.dtype)])
-    return mstack, dstack
-
-
-# One-dispatch result scatter: slicing B rows off a bucket result with
-# eager ``out[j]`` indexing costs one dispatch per request (~100 us each on
-# CPU — measured to dominate the flush); this jitted splitter materializes
-# all B per-request answers in a single call. No donation: the row outputs
-# are strictly smaller than the stacked input, so XLA could never alias it.
-# Pytree-general (tree_map over an array leaf is the old ``out[j]``): a
-# markov steady-state bucket's result is a stacked SteadyStateResult, and
-# each request resolves with its own per-member slice of every field.
-@functools.partial(jax.jit, static_argnames=("b",))
+# The resolve stage's row pick. A bucket program returns one output per
+# padded slot, each already its own device buffer (a markov steady-state
+# member is a whole per-member SteadyStateResult), so picking the ``b``
+# member rows is host-only: no device call, filler slots dropped. Module
+# level so the resolve stage reaches it through the module attribute.
 def _split_rows(out, *, b: int):
-    return tuple(jax.tree_util.tree_map(lambda leaf: leaf[j], out)
-                 for j in range(b))
+    return tuple(out[:b])
+
+
+def _bucket_program(per_stack):
+    """One jitted device program for a whole bucket: it takes the padded
+    bucket's member payloads as separate arguments, stacks them (leaf by
+    leaf, so an evolve member's (operand, dists) pair stacks into a pair
+    of stacks), runs ``per_stack`` on the stack, and returns one output
+    per member, every pytree leaf sliced to that member's row. Stacking
+    and slicing move data and compute nothing, so each member's answer is
+    what ``per_stack`` computes for it. No donation: the arguments are
+    the caller's arrays (and the engine's shared filler); the stack is an
+    intermediate the program owns."""
+    def program(*members):
+        stack = jax.tree_util.tree_map(lambda *rows: jnp.stack(rows),
+                                       *members)
+        out = per_stack(stack)
+        return tuple(jax.tree_util.tree_map(lambda leaf: leaf[j], out)
+                     for j in range(len(members)))
+
+    return jax.jit(program)
 
 
 def bucket_batch(b: int, max_batch: int = 64) -> int:
@@ -446,9 +427,9 @@ def bucket_batch(b: int, max_batch: int = 64) -> int:
 
 @dataclasses.dataclass
 class _Dispatched:
-    """One bucket chunk whose executable was called: its (padded) device
-    output, the member count, the route, and — when tracing — the span
-    tags of its stages."""
+    """One bucket chunk whose program was called: its per-slot device
+    outputs (filler slots included), the member count, the route, and —
+    when tracing — the span tags of its stages."""
     out: object
     b: int
     route: str
@@ -672,6 +653,7 @@ class MatFnEngine:
         self._fastmm_cache: dict = {}
         self._pending: List[MatFnRequest] = []
         self._executables: dict = {}
+        self._fillers: dict = {}          # (n, dtype, dists rows) -> zeros
         # Daemon state (inert until start()).
         self._cv = threading.Condition()
         self._daemon: Optional[threading.Thread] = None
@@ -708,7 +690,7 @@ class MatFnEngine:
             if self.tracer.enabled else None
         self._rid = itertools.count()
         self.stats = _Stats({
-            "requests": 0, "buckets": 0, "compiles": 0,
+            "requests": 0, "buckets": 0, "dispatches": 0, "compiles": 0,
             "cache_hits": 0, "padded_slots": 0,
             "stragglers": 0, "retries": 0,
             "routes": {r: 0 for r in ROUTES},
@@ -755,7 +737,7 @@ class MatFnEngine:
         the math; ignored in synchronous mode.
 
         ``operand`` may be a jax or numpy array (kept as-is — the bucket
-        assembler stacks them in one jitted call) or anything
+        program stacks them inside its one jitted call) or anything
         ``jnp.asarray`` accepts. The as-is fast path matters: an asarray
         per submit costs more than a whole warm serial call at small n.
         Non-canonical numpy dtypes (f64 under disabled x64 — numpy's
@@ -1075,107 +1057,103 @@ class MatFnEngine:
         if exe is not None:
             self.stats["cache_hits"] += 1
             return key, exe, False
-        if op == "markov" and _is_evolve(power):
-            # The evolve route: one jitted program mapping each (operand,
-            # dists) pair through the binary-decomposition vector-matrix
-            # chain. lax.map for the same reason as expm below — compile
-            # size stays O(1) in the bucket batch, and each member's
-            # big-B dense fallback decision (the autotuned ``markov``
-            # threshold, resolved at trace time) is per-shape anyway.
-            from repro.core.markov import evolve_distributions
-            steps = power[1]
-            cpu_max_n, _ = self.thresholds_for(dtype)
-            backend = "xla" if n <= cpu_max_n else self._chain_backend
-
-            def per_member(pair):
-                mat, dist = pair
-                return evolve_distributions(dist, mat, steps,
-                                            backend=backend, validate=False)
-
-            # Donate the dists stack only: the (bpad, B, n) output aliases
-            # it exactly, while the (bpad, n, n) matrix stack could never
-            # alias and would only warn.
-            jitted = jax.jit(lambda mats, dists: lax.map(per_member,
-                                                         (mats, dists)),
-                             donate_argnums=1)
-            exe = lambda pair: jitted(*pair)
-        elif op == "markov" and route == "sharded":
-            # Mesh-resident steady state: the convergence loop runs on a
-            # ShardedMatmulChain (pad + 2-D sharding committed once, every
-            # squaring a donated collective step) — same structure as
-            # expm_sharded's loop. The chain drives its own jitted steps;
-            # no outer jit, no batch dim (single matrix by construction).
-            from repro.core.distributed import ShardedMatmulChain
-            from repro.core.markov import steady_state
-            mesh = self.mesh
-            chain = ShardedMatmulChain(n, jnp.dtype(dtype), mesh,
-                                       donate=False)
-            exe = lambda x: jax.tree_util.tree_map(
-                lambda leaf: leaf[None],
-                steady_state(x[0], validate=False, chain=chain))
-        elif op == "markov":
-            # Steady state on the local routes: lax.map of the per-matrix
-            # convergence loop, so every bucket member keeps its OWN
-            # squaring count (a stacked loop would square everyone to the
-            # slowest mixer) and answers stay bit-identical to per-matrix
-            # steady_state calls.
-            from repro.core.markov import steady_state
-            backend = (self._chain_backend if route == "chain"
-                       else self._fastmm_backend if route == "fastmm"
-                       else "xla")
-            per_matrix = functools.partial(steady_state, validate=False,
-                                           backend=backend)
-            exe = jax.jit(lambda x: lax.map(per_matrix, x),
-                          donate_argnums=0)
-        elif route == "sharded":
+        if route == "sharded":
             # The sharded chain drives its own jitted collective steps (one
-            # compiled step shared per mesh/shape) — no outer jit, and no
-            # batch dim: the bucket is a single matrix by construction.
-            from repro.core.distributed import expm_sharded, matpow_sharded
+            # compiled step shared per mesh/shape): no outer jit and no
+            # stack, since the bucket is one matrix by construction. It
+            # answers a one-member tuple, like every bucket program.
+            from repro.core import distributed
             mesh = self.mesh
-            if op == "matpow":
-                exe = lambda x: matpow_sharded(x[0], power, mesh)[None]
+            if op == "markov":
+                # Mesh-resident steady state: the convergence loop runs on
+                # a ShardedMatmulChain (pad + 2-D sharding committed once,
+                # every squaring a donated collective step), the same
+                # structure as expm_sharded's loop.
+                from repro.core.markov import steady_state
+                chain = distributed.ShardedMatmulChain(
+                    n, jnp.dtype(dtype), mesh, donate=False)
+                exe = lambda x: (steady_state(x, validate=False,
+                                              chain=chain),)
+            elif op == "matpow":
+                exe = lambda x: (distributed.matpow_sharded(x, power,
+                                                            mesh),)
             else:
-                exe = lambda x: expm_sharded(x[0], mesh)[None]
+                exe = lambda x: (distributed.expm_sharded(x, mesh),)
         else:
-            backend = (self._chain_backend if route == "chain"
-                       else self._fastmm_backend if route == "fastmm"
-                       else "xla")
-            if op == "matpow":
-                fn = functools.partial(batched_matpow, p=power,
-                                       backend=backend)
-            else:
-                # lax.map, NOT a stacked expm: the per-matrix 2-D program
-                # lowers identically inside the loop, so bucket answers stay
-                # bit-identical to per-matrix expm calls (a fused batched
-                # expm reassociates the elementwise Pade chain and drifts by
-                # ~1 ulp at B > 1), and each matrix keeps its own
-                # data-dependent squaring count instead of masking to the
-                # stack max. One executable per bucket still amortizes
-                # dispatch across the batch.
-                per_matrix = functools.partial(_expm, backend=backend)
-                fn = lambda x: lax.map(per_matrix, x)
-            # The padded stack is engine-built filler + copies of nothing
-            # the caller holds, so donating it lets XLA run the whole
-            # bucket in the request buffer's HBM.
-            exe = jax.jit(fn, donate_argnums=0)
+            exe = _bucket_program(self._per_stack(op, route, n, dtype,
+                                                  power))
         self._executables[key] = exe
         self.stats["compiles"] += 1
         return key, exe, True
+
+    def _per_stack(self, op: str, route: str, n: int, dtype: str, power):
+        """What a local route's bucket program runs on the stacked bucket:
+        one program per (op, route), whatever the padded batch."""
+        backend = (self._chain_backend if route == "chain"
+                   else self._fastmm_backend if route == "fastmm"
+                   else "xla")
+        if op == "markov" and _is_evolve(power):
+            # The evolve route: lax.map of each (operand, dists) pair
+            # through the binary-decomposition vector-matrix chain, for
+            # the same reason as expm below: compile size stays O(1) in
+            # the bucket batch, and each member's big-B dense fallback
+            # decision (the autotuned ``markov`` threshold, resolved at
+            # trace time) is per-shape anyway.
+            from repro.core.markov import evolve_distributions
+            cpu_max_n, _ = self.thresholds_for(dtype)
+            per_member = functools.partial(
+                evolve_distributions, steps=power[1],
+                backend="xla" if n <= cpu_max_n else self._chain_backend,
+                validate=False)
+            return lambda pairs: lax.map(
+                lambda pair: per_member(pair[1], pair[0]), pairs)
+        if op == "markov":
+            # Steady state: lax.map of the per-matrix convergence loop, so
+            # every bucket member keeps its OWN squaring count (a stacked
+            # loop would square everyone to the slowest mixer) and answers
+            # stay bit-identical to per-matrix steady_state calls.
+            from repro.core.markov import steady_state
+            per_matrix = functools.partial(steady_state, validate=False,
+                                           backend=backend)
+            return lambda stack: lax.map(per_matrix, stack)
+        if op == "matpow":
+            return functools.partial(batched_matpow, p=power, backend=backend)
+        # lax.map, NOT a stacked expm: the per-matrix 2-D program lowers
+        # identically inside the loop, so bucket answers stay bit-identical
+        # to per-matrix expm calls (a fused batched expm reassociates the
+        # elementwise Pade chain and drifts by ~1 ulp at B > 1), and each
+        # matrix keeps its own data-dependent squaring count instead of
+        # masking to the stack max. One program per bucket still amortizes
+        # dispatch across the batch.
+        per_matrix = functools.partial(_expm, backend=backend)
+        return lambda stack: lax.map(per_matrix, stack)
+
+    def _filler(self, n: int, dtype: str, power):
+        """The zero payload that pads a bucket of one class: an (n, n)
+        matrix, or for evolve an (operand, dists) pair. One per class,
+        shared by every padded slot; filler rows are never delivered."""
+        rows = power[2] if _is_evolve(power) else None
+        key = (n, dtype, rows)
+        filler = self._fillers.get(key)
+        if filler is None:
+            mat = jnp.zeros((n, n), dtype)
+            filler = mat if rows is None \
+                else (mat, jnp.zeros((rows, n), dtype))
+            filler = self._fillers.setdefault(key, filler)
+        return filler
 
     def warm(self, op: str, n: int, dtype=jnp.float32, power: int = 1,
              batches=None) -> int:
         """Precompile everything one traffic class will need.
 
-        Runs the REAL bucket path (one-dispatch assembler, executable,
-        one-dispatch splitter) on zero stacks for every batch size in
-        ``batches`` — default 1..``max_batch``, because the assembler and
-        splitter specialize on the exact member count, not just the padded
-        bucket shape, so a deadline-triggered partial bucket of a size
-        never seen before would otherwise pay its compiles on the latency
-        path. Call before opening traffic (warm chunks count into the
-        engine stats like any other bucket execution); returns the number
-        of chunks warmed.
+        Runs the REAL bucket path (one bucket program, then the host-side
+        row pick) on zero payloads for every batch size in ``batches``.
+        A bucket program is keyed on the padded size alone, so the default
+        is one batch of each distinct padded size (``bucket_batch`` of
+        1..``max_batch``: 7 at ``max_batch`` 64); an explicit list runs
+        each size on its padded size's program. Call before opening
+        traffic (warm chunks count into the engine stats like any other
+        bucket execution); returns the number of chunks warmed.
 
         In daemon mode each warm chunk runs ON its route's execution
         stream (queued FIFO behind any dispatched buckets): the compile
@@ -1190,8 +1168,10 @@ class MatFnEngine:
         """
         dtype = jnp.dtype(dtype)
         if batches is None:
-            batches = range(1, self.max_batch + 1)
+            batches = sorted({bucket_batch(b, self.max_batch)
+                              for b in range(1, self.max_batch + 1)})
         power = power if op == "matpow" else -1
+        zeros = self._filler(n, dtype.name, power)
         with self._cv:
             pool = self._pool
 
@@ -1201,7 +1181,7 @@ class MatFnEngine:
 
         count, jobs = 0, []
         for b in batches:
-            operands = [jnp.zeros((n, n), dtype) for _ in range(b)]
+            operands = [zeros] * b
             if pool is not None:
                 stream = self._streams.stream_for(
                     self.route_for(n, b, dtype.name))
@@ -1217,16 +1197,18 @@ class MatFnEngine:
     # -- bucket execution core (shared by flush() and the daemon) ----------
     def _run_chunk(self, op: str, n: int, dtype: str, power: int,
                    operands) -> _Dispatched:
-        """Assemble and execute ONE bucket chunk (<= max_batch); the caller
-        splits and delivers its rows with :meth:`_resolve_chunk`.
+        """Execute ONE bucket chunk (<= max_batch) as one device program;
+        the caller picks and delivers its rows with :meth:`_resolve_chunk`.
 
         This is the single execution core both the synchronous ``flush``
         and the daemon scheduler run, which is what keeps daemon answers
-        bit-identical to synchronous ones: same assembly, same executable
-        cache, same routes.
+        bit-identical to synchronous ones: same program, same executable
+        cache, same routes. The program takes the ``b`` member payloads
+        and ``bpad - b`` references to the class's zero filler as
+        separate arguments, and stacks, runs and splits inside itself.
 
-        Stage timing: assemble (operand stack + pad + executable lookup)
-        and execute (the jitted call; device-complete only under
+        Stage timing: assemble (executable lookup and the argument list)
+        and execute (the one dispatch; device-complete only under
         ``profile=True``) feed the ``stage`` histograms behind
         ``stats()["stages"]`` and, when tracing, lexical spans on the
         executing thread's track (ring records and profiler annotations),
@@ -1249,17 +1231,11 @@ class MatFnEngine:
         clk = self._clock.now
         t0 = clk()
         with assemble:
-            if _is_evolve(power):
-                # Evolve operands are (operand, dists) pairs (see
-                # MatFnRequest.payload); both stacks assemble in one
-                # dispatch.
-                stack = _assemble_pairs(tuple(m for m, _ in operands),
-                                        tuple(d for _, d in operands),
-                                        bpad=bpad)
-            else:
-                stack = _assemble(tuple(operands), bpad=bpad)
             key, exe, fresh = self._executable(op, route, bpad, n, dtype,
                                                power)
+            members = list(operands)
+            if bpad > b:
+                members += [self._filler(n, dtype, power)] * (bpad - b)
             assemble.tag(cold=fresh)
         t1 = clk()
         with execute:
@@ -1270,10 +1246,10 @@ class MatFnEngine:
                 # not the engine clock: this dt is honest device wall
                 # time even under a ManualClock test.
                 tp = time.perf_counter()
-                out = jax.block_until_ready(exe(stack))
+                out = jax.block_until_ready(exe(*members))
                 dt = time.perf_counter() - tp
             else:
-                out = exe(stack)
+                out = exe(*members)
                 dt = None
         t2 = clk()
         self.metrics.record("stage", t1 - t0, stage="assemble", route=route)
@@ -1289,6 +1265,7 @@ class MatFnEngine:
         with self._stats_lock:
             self.stats["padded_slots"] += bpad - b
             self.stats["buckets"] += 1
+            self.stats["dispatches"] += 1
             self.stats["routes"][route] += 1
             self.stats["last_flush"].append(
                 {"key": key, "requests": b, "padded_batch": bpad,
@@ -1296,11 +1273,12 @@ class MatFnEngine:
         return _Dispatched(out, b, route, tags)
 
     def _resolve_chunk(self, chunk: _Dispatched, deliver=None) -> tuple:
-        """The resolve stage of one chunk: split its B result rows off the
-        padded output (dropping the filler slots) and hand them to
-        ``deliver`` (the futures' resolution loop, or the synchronous
-        result slots). Returns the rows. Feeds ``stage=resolve`` and, when
-        tracing, the ``bucket.resolve`` span."""
+        """The resolve stage of one chunk: pick its B member rows off the
+        program's per-slot outputs on the host (dropping the filler
+        slots; no device call) and hand them to ``deliver`` (the futures'
+        resolution loop, or the synchronous result slots). Returns the
+        rows. Feeds ``stage=resolve`` and, when tracing, the
+        ``bucket.resolve`` span."""
         if chunk.tags is not None:
             span = self.tracer.span("bucket.resolve",
                                     track=threading.current_thread().name,
@@ -1966,6 +1944,7 @@ class MatFnEngine:
                 return {
                     "requests": self.stats["requests"],
                     "buckets": self.stats["buckets"],
+                    "dispatches": self.stats["dispatches"],
                     "compiles": self.stats["compiles"],
                     "cache_hits": self.stats["cache_hits"],
                     "padded_slots": self.stats["padded_slots"],

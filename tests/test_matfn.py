@@ -24,6 +24,7 @@ from repro.core import (BatchedMatmulChain, batched_expm, batched_matpow,
                         expm, matpow_binary)
 from repro.kernels import autotune, ops
 from repro.serve.matfn import MatFnEngine, MatFnRequest, bucket_batch
+from repro.serve.scheduler import ManualClock
 
 CHAIN = "pallas_chain_interpret"
 
@@ -306,6 +307,152 @@ class TestEngine:
         eng.flush()
         rows = eng.stats["last_flush"]
         assert len(rows) == 1 and rows[0]["seconds"] > 0
+
+
+# The four bucket classes of the local routes: op, power slot, and whether
+# each request carries start distributions (the evolve class).
+_KINDS = {"expm": ("expm", 1, False), "matpow": ("matpow", 7, False),
+          "steady_state": ("markov", 1, False), "evolve": ("markov", 9, True)}
+_KIND_REFS = {}
+
+
+def _kind_payload(kind, n, seed):
+    """One request's (operand, dists) for a bucket class; dists is None
+    outside evolve. Markov operands are row-stochastic."""
+    rng = np.random.default_rng(seed)
+    if _KINDS[kind][0] != "markov":
+        return jnp.asarray(rng.standard_normal((n, n)) * 0.3,
+                           jnp.float32), None
+    p = rng.random((n, n)) + 0.05
+    p = jnp.asarray(p / p.sum(1, keepdims=True), jnp.float32)
+    if not _KINDS[kind][2]:
+        return p, None
+    d = rng.random((3, n))
+    return p, jnp.asarray(d / d.sum(1, keepdims=True), jnp.float32)
+
+
+def _kind_ref(kind, a, d):
+    """The per-matrix jitted call a bucket member must equal bit for bit."""
+    if kind not in _KIND_REFS:
+        from repro.core.markov import evolve_distributions, steady_state
+        _KIND_REFS[kind] = jax.jit({
+            "expm": lambda x, _d: expm(x),
+            "matpow": lambda x, _d: matpow_binary(x, 7),
+            "steady_state": lambda x, _d: steady_state(x, validate=False),
+            "evolve": lambda x, dd: evolve_distributions(dd, x, 9,
+                                                         validate=False),
+        }[kind])
+    return _KIND_REFS[kind](a, d)
+
+
+def _submit_kind(eng, kind, a, d):
+    op, power, _ = _KINDS[kind]
+    return eng.submit(op, a, power=power, dists=d)
+
+
+def _assert_same(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+class TestOneProgramPerBucket:
+    """Each bucket runs as ONE device program: the members and filler
+    references go in as separate arguments, the stack and the per-slot
+    split happen inside it, and the resolve stage keeps the members' rows
+    on the host."""
+
+    @pytest.mark.parametrize("b", [1, 5, 8, 63, 64])
+    @pytest.mark.parametrize("kind", list(_KINDS))
+    def test_bucket_answers_bit_identical_per_member(self, kind, b):
+        eng = MatFnEngine(max_batch=64, clock=ManualClock(),
+                          max_delay_ms=10.0)
+        delivered = []
+        real = eng._resolve
+
+        def counting(fut, **kw):
+            delivered.append(fut)
+            return real(fut, **kw)
+
+        eng._resolve = counting
+        work = [_kind_payload(kind, 8, seed=100 + i) for i in range(b)]
+        with eng:
+            futs = [_submit_kind(eng, kind, a, d) for a, d in work]
+            eng.kick()
+            got = [f.result(timeout=30.0) for f in futs]
+            snap = eng.stats()
+        assert snap["buckets"] == 1
+        assert snap["padded_slots"] == bucket_batch(b) - b
+        assert sorted(map(id, delivered)) == sorted(map(id, futs))
+        for (a, d), g in zip(work, got):
+            _assert_same(g, _kind_ref(kind, a, d))
+
+    @pytest.mark.parametrize("mode", ["flush", "daemon"])
+    def test_one_dispatch_per_bucket(self, mode):
+        eng = MatFnEngine(max_batch=4, clock=ManualClock(),
+                          max_delay_ms=10.0)
+        work = [("matpow", _stack(1, n, seed=i)[0], p)
+                for i, (n, p) in enumerate([(8, 3)] * 6 + [(12, 3)] * 3
+                                           + [(8, 5)] * 2)]
+        work += [("expm", _stack(1, 8, seed=20 + i)[0], 1) for i in range(3)]
+        if mode == "daemon":
+            with eng:
+                futs = [eng.submit(op, a, power=p) for op, a, p in work]
+                eng.kick()
+                for f in futs:
+                    f.result(timeout=30.0)
+                snap = eng.stats()
+        else:
+            for op, a, p in work:
+                eng.submit(op, a, power=p)
+            eng.flush()
+            snap = eng.stats()
+        # (8, 3) x6 -> 4 + 2; (12, 3) x3; (8, 5) x2; expm x3
+        assert snap["buckets"] == 5
+        assert snap["routes"]["xla"] == snap["buckets"]
+        assert snap["dispatches"] == snap["buckets"]
+        assert eng.stats["dispatches"] == eng.stats["buckets"]
+
+    @pytest.mark.parametrize("kind", list(_KINDS))
+    def test_caller_operands_stay_usable(self, kind):
+        eng = MatFnEngine()
+        work = [_kind_payload(kind, 8, seed=200 + i) for i in range(3)]
+        # The values from equal-seed twins: a host view of the operands
+        # themselves would pin their buffers against donation on the CPU.
+        before = [jax.tree_util.tree_map(
+            np.asarray, _kind_payload(kind, 8, seed=200 + i))
+            for i in range(3)]
+        for a, d in work:
+            _submit_kind(eng, kind, a, d)
+        eng.flush()                        # one bucket of 3, padded to 4
+        assert eng.stats["padded_slots"] == 1
+        for w, host in zip(work, before):
+            for arr, want in zip(jax.tree_util.tree_leaves(w),
+                                 jax.tree_util.tree_leaves(host),
+                                 strict=True):
+                assert not arr.is_deleted()
+                np.testing.assert_array_equal(np.asarray(arr), want)
+        for a, d in work:                  # and usable in another bucket
+            _submit_kind(eng, kind, a, d)
+        for (a, d), g in zip(work, eng.flush()):
+            _assert_same(g, _kind_ref(kind, a, d))
+
+    @pytest.mark.parametrize("max_batch, sizes", [
+        (64, 7), (5, 4), (1, 1)])
+    def test_warm_compiles_each_padded_size_once(self, max_batch, sizes):
+        eng = MatFnEngine(max_batch=max_batch)
+        assert eng.warm("expm", 8) == sizes
+        assert eng.stats["compiles"] == sizes
+        assert eng.stats["dispatches"] == sizes
+        # A bucket size warm did not run reuses its padded size's program,
+        # which was traced for that padded size alone.
+        b = max(1, max_batch - 1)
+        for i in range(b):
+            eng.submit("expm", _stack(1, 8, seed=300 + i)[0])
+        eng.flush()
+        assert eng.stats["compiles"] == sizes
+        assert all(exe._cache_size() == 1
+                   for exe in eng._executables.values())
 
 
 class TestHeterogeneousDispatch:
