@@ -66,52 +66,62 @@ FOUR_CHIP_N = 8192
 FOUR_CHIP_POWER = 64
 
 # --- error bounds ---------------------------------------------------------
-# On a TPU v5e every f32 dot here, XLA's at default precision and the
-# Pallas kernels' alike, runs one bf16 pass: each input rounded to 8
-# significant bits (unit roundoff U = 2**-9), products accumulated in f32.
-# (A 1024 x 1024 product is off by 2.35e-3 there, as a host emulation with
-# bf16-rounded inputs predicts.) Each bound holds at that precision with a
-# margin of 3x or more over a host emulation of the same algorithm on
-# operands like these, with every matmul input rounded to bf16. Each
-# check also computes the error of wrong answers (a zero answer, the
-# transposed answer, its own input, another request's answer) and fails
-# unless every one of them lands above the bound.
+# On a TPU v5e the xla route's f32 dots (and the sharded route's) run at
+# JAX's default, one bf16 pass: each input rounded to 8 significant bits
+# (unit roundoff U = 2**-9), products accumulated in f32. The chain and
+# fastmm routes, and the evolve route's squarings, run every product at
+# core.matpow.CHAIN_PRECISION, three bf16 passes (each product off by
+# about 2**-16 of its size; U_HIGH). The one-chip bounds below sit about
+# ten times above what seed 0 read on a v5e at those precisions (one-pass
+# readings, where the route ran one pass before, in brackets). Each check
+# also computes the error of wrong answers (a zero answer, the transposed
+# answer, its own input, another request's answer) and fails unless every
+# one of them lands above the bound.
 U_BF16 = 2.0 ** -9
+U_HIGH = 2.0 ** -16
 
 BOUNDS = {
     # A^64 at n = 1024 (leak 0.008): rounding shifts each squaring's row
-    # sums by ~U/sqrt(n), and binary powering multiplies the shift of the
-    # first squaring by ~p. Emulated 3.5e-3.
-    "matpow_p64": 0.015,
-    # A^1000 at n = 1024 (leak 0.001): the slow modes' 0.999**1000 turns a
-    # 1e-4 rounding of the in-community mass into ~10%. Emulated 0.153.
-    "matpow_p1000": 0.5,
-    # A^64 at n = 4096 with two Strassen levels. Emulated 0.014.
-    "fastmm_p64": 0.06,
-    # A^64 at n = 256 (daemon). Emulated 8.3e-3 for one operand; the worst
-    # of 75 on a v5e was 2.4e-2.
-    "daemon_matpow_p64": 0.07,
+    # sums, and binary powering multiplies the shift of the first squaring
+    # by ~p. Read 4.6e-6 (one pass: 4.2e-3).
+    "matpow_p64": 5e-5,
+    # A^1000 at n = 1024 (leak 0.001): the slow modes' 0.999**1000 amplify
+    # a rounding of the in-community mass. Read 4.2e-5 (one pass: 0.119).
+    "matpow_p1000": 5e-4,
+    # A^64 at n = 4096 with two Strassen levels. Read 1.3e-5 (one pass:
+    # 1.6e-2).
+    "fastmm_p64": 1.5e-4,
+    # A^64 at n = 256 (daemon): the worst of 75 operands read 2.1e-5 (one
+    # pass: 2.4e-2).
+    "daemon_matpow_p64": 2.5e-4,
     # pi at n = 2048 (leak 0.25), one Strassen level: pi is renormalized,
-    # so row-sum drift cancels. Emulated 1.8e-3 without Strassen.
-    "steady_state": 0.02,
-    # d @ P^1000 at n = 2048 (leak 0.001), like matpow_p1000. Emulated 0.158.
-    "evolve": 0.5,
+    # so row-sum drift cancels. Read 9.4e-6.
+    "steady_state": 1e-4,
+    # d @ P^1000 at n = 2048 (leak 0.001), like matpow_p1000: the squarings
+    # at three passes, the 64-row products (ops.dense_matmul) at one.
+    # Read 1.3e-3 (one pass throughout: 0.149).
+    "evolve": 0.015,
     # Four chips at n = 8192: sharded against one-chip, each answer within
     # its one-chip bound of the f64 answer. For expm the sum of two
     # measured worst cases, 0.82 U ||A||_1, is near what a transposed
     # answer gives at n = 8192 (0.048 = 2.4 U ||A||_1 at ||A||_1 = 10), so
-    # the bound sits between them: 0.75 * expm_bound = 1.5 U ||A||_1.
+    # the bound sits between them: 0.75 * expm_bound = 1.5 U ||A||_1. The
+    # sharded route still runs one pass; its one-chip counterpart now runs
+    # three, so the gap is about the sharded answer's own error, below
+    # the sum these bounds were set from (not rerun on four chips since).
     "sharded_matpow": 0.03,
     "sharded_markov": 0.04,
 }
 
 
-def expm_bound(a) -> float:
-    """Pade-13 evaluated with bf16-rounded products loses ~U per product,
-    scaled by the operand: emulated errors at ||A||_1 in [2, 10] stay
-    below 0.3 U ||A||_1 (n = 61 and n = 1024), and the worst of 64 codon
-    generators on a v5e was 0.41 U ||A||_1."""
-    return 2 * U_BF16 * float(np.abs(a).sum(0).max())
+def expm_bound(a, u: float = U_BF16) -> float:
+    """Pade-13 loses ~u per product, scaled by the operand: at one bf16
+    pass (the xla route, u = U_BF16) emulated errors at ||A||_1 in [2, 10]
+    stay below 0.3 U ||A||_1 (n = 61 and n = 1024), and the worst of 64
+    codon generators on a v5e was 0.41 U ||A||_1. At three passes (the
+    chain route, u = U_HIGH) n = 1024, ||A||_1 in [6, 10] read 1.7e-5 on
+    a v5e, about 0.15 U_HIGH ||A||_1."""
+    return 2 * u * float(np.abs(a).sum(0).max())
 
 
 def fail(msg: str) -> None:
@@ -263,7 +273,7 @@ def one_chip_requests(rng, sizes) -> List[Request]:
                                    else "matpow_p1000"]))
     a = generator(rng, n, rng.uniform(6.0, 10.0))
     reqs.append(Request(f"expm n={n}", "chain", "expm", a, 1, None,
-                        ref_expm(a), expm_bound(a)))
+                        ref_expm(a), expm_bound(a, U_HIGH)))
     n, p = sizes["fastmm_n"], sizes["fastmm_power"]
     a = stochastic(rng, n, leak=0.008)
     reqs.append(Request(f"matpow n={n} p={p}", "fastmm", "matpow", a, p, None,

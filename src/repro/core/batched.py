@@ -46,20 +46,22 @@ __all__ = ["BatchedMatmulChain", "batched_matpow", "batched_expm"]
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype",
-                     "vmem_limit", "panel_limit"),
+                     "vmem_limit", "panel_limit", "precision"),
     donate_argnums=(0,),
 )
 def _batched_square_step(a, *, block_m, block_n, block_k, interpret, out_dtype,
-                         vmem_limit, panel_limit):
+                         vmem_limit, panel_limit, precision):
     return jax.vmap(lambda x: square_pallas(
         x, block_m=block_m, block_n=block_n, block_k=block_k,
         interpret=interpret, out_dtype=out_dtype,
-        vmem_limit=vmem_limit, panel_limit=panel_limit))(a)
+        vmem_limit=vmem_limit, panel_limit=panel_limit,
+        precision=precision))(a)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _batched_square_step_ref(a):
-    return _ref.matmul_ref(a, a)
+@functools.partial(jax.jit, static_argnames=("precision",),
+                   donate_argnums=(0,))
+def _batched_square_step_ref(a, *, precision):
+    return _ref.matmul_ref(a, a, precision=precision)
 
 
 class BatchedMatmulChain(_kops.MatmulChain):
@@ -80,12 +82,13 @@ class BatchedMatmulChain(_kops.MatmulChain):
     """
 
     def __init__(self, batch: int, n: int, dtype, *, interpret: bool = False,
-                 blocks=None, donate: bool = True, fast=False):
+                 blocks=None, donate: bool = True, fast=False,
+                 precision=None):
         if not isinstance(batch, int) or batch < 1:
             raise ValueError(f"batched chains need a static batch >= 1, "
                              f"got {batch!r}")
         super().__init__(n, dtype, interpret=interpret, blocks=blocks,
-                         donate=donate, fast=fast)
+                         donate=donate, fast=fast, precision=precision)
         self.batch = batch
 
     # -- chain boundary ----------------------------------------------------
@@ -106,13 +109,14 @@ class BatchedMatmulChain(_kops.MatmulChain):
                 # and batches its leaves natively — already ONE dispatch.
                 return super().square(x)
             if not self.active:
-                return _batched_square_step_ref(x)
+                return _batched_square_step_ref(x, precision=self.precision)
             bm, bn, bk = self.blocks
             vmem_limit, panel_limit = self.tiers
             return _batched_square_step(
                 x, block_m=bm, block_n=bn, block_k=bk,
                 interpret=self.interpret, out_dtype=self.dtype,
-                vmem_limit=vmem_limit, panel_limit=panel_limit)
+                vmem_limit=vmem_limit, panel_limit=panel_limit,
+                precision=self.precision)
         # Traced (outer jit / lax loop): donation is inert, the base class
         # vmaps the kernel per matrix and XLA fuses the batch itself.
         return super().square(x)
@@ -155,7 +159,8 @@ def batched_matpow(a: jax.Array, p: int, *, backend: str = "xla") -> jax.Array:
         return jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=a.dtype), a.shape)
     chain = BatchedMatmulChain(a.shape[0], a.shape[-1], a.dtype,
                                interpret=interpret,
-                               fast=backend in _matpow._FAST_BACKENDS)
+                               fast=backend in _matpow._FAST_BACKENDS,
+                               precision=_matpow.precision_for(backend))
     return chain.unpad(_matpow._binary_chain_body(chain.pad(a), p, chain))
 
 

@@ -12,6 +12,9 @@ Pure JAX (jit/vmap/grad-safe); fp32 or fp64.
 
 from __future__ import annotations
 
+import functools
+import operator
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -30,14 +33,16 @@ _PADE13 = (
 _THETA13 = 5.371920351148152  # 1-norm threshold for Pade-13
 
 
-def _pade13(a: jax.Array, ident: jax.Array):
+def _pade13(a: jax.Array, ident: jax.Array, mm=operator.matmul):
+    """Numerator and denominator terms of Pade-13 at ``a``; ``mm`` is the
+    route's product (``a @ b`` at JAX's default precision unless given)."""
     b = _PADE13
-    a2 = a @ a
-    a4 = a2 @ a2
-    a6 = a2 @ a4
-    u = a @ (a6 @ (b[13] * a6 + b[11] * a4 + b[9] * a2)
-             + b[7] * a6 + b[5] * a4 + b[3] * a2 + b[1] * ident)
-    v = (a6 @ (b[12] * a6 + b[10] * a4 + b[8] * a2)
+    a2 = mm(a, a)
+    a4 = mm(a2, a2)
+    a6 = mm(a2, a4)
+    u = mm(a, mm(a6, b[13] * a6 + b[11] * a4 + b[9] * a2)
+           + b[7] * a6 + b[5] * a4 + b[3] * a2 + b[1] * ident)
+    v = (mm(a6, b[12] * a6 + b[10] * a4 + b[8] * a2)
          + b[6] * a6 + b[4] * a4 + b[2] * a2 + b[0] * ident)
     return u, v
 
@@ -56,7 +61,9 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
     :func:`repro.core.matpow.matmul_backend`; ``"pallas_chain"`` pads the
     Pade result once, squares on the padded buffer through the single-ref
     kernel, and un-pads once at the end. The small fixed Pade polynomial
-    (6 matmuls + one solve) stays on XLA — it is not a chain.
+    (6 matmuls + one solve) stays on XLA — it is not a chain — at the
+    backend's precision (``matpow.precision_for``): JAX's default for
+    ``xla``, the chain's precision for the chain backends.
     """
     if a.ndim < 2 or a.shape[-1] != a.shape[-2]:
         raise ValueError(f"expm needs square matrices, got {a.shape}")
@@ -78,8 +85,10 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
         scaled = compute / (2.0 ** s.astype(compute.dtype))
 
     ident = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=compute.dtype), compute.shape)
+    mm = functools.partial(jnp.matmul,
+                           precision=matpow.precision_for(backend))
     with jax.named_scope("expm.pade"):
-        u, v = _pade13(scaled, ident)
+        u, v = _pade13(scaled, ident, mm)
     with jax.named_scope("expm.solve"):
         # r = (v - u)^-1 (v + u)
         r = jnp.linalg.solve(v - u, v + u)

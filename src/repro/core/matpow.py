@@ -43,6 +43,7 @@ __all__ = [
     "matpow_binary_traced",
     "matmul_backend",
     "chain_for",
+    "precision_for",
 ]
 
 
@@ -55,6 +56,26 @@ _CHAIN_BACKENDS = {"pallas_chain": False, "pallas_chain_interpret": True,
 
 #: Chain backends whose multiplies take the Strassen route.
 _FAST_BACKENDS = frozenset({"pallas_fastmm", "pallas_fastmm_interpret"})
+
+#: The precision of every product a chain backend computes: expm's Pade
+#: products, each squaring and combine, and Strassen's leaves. On a TPU
+#: v5e JAX's default runs an f32 product as one bf16 pass (8 significant
+#: bits per input), and a chain of s squarings multiplies what each
+#: product carries by about 2**s: the 2016-state tandem CTMC's e^{10 Q}
+#: came out no better than bfloat16 (row-L1 error 0.66 to 11). ``HIGH`` is
+#: three bf16 passes (XLA's bf16_3x; ``kernels.matmul.tile_dot`` in the
+#: Pallas kernels): 1e-3 to 6e-3 there, at 8.2 ms of device time an
+#: answer against 11.3 ms at ``HIGHEST`` (Mosaic's f32 contract
+#: precision, 9e-5). The ``xla`` and per-call ``pallas`` backends keep
+#: JAX's default, so their programs are unchanged. The Pallas kernels
+#: apply it to f32 operands only (``kernels.matmul.kernel_precision``).
+CHAIN_PRECISION = lax.Precision.HIGH
+
+
+def precision_for(backend: str):
+    """The dot precision ``backend`` computes its products at: None (JAX's
+    default) or :data:`CHAIN_PRECISION` for the fused chain backends."""
+    return CHAIN_PRECISION if backend in _CHAIN_BACKENDS else None
 
 
 def matmul_backend(backend: str = "xla", precision=None) -> Callable:
@@ -71,6 +92,9 @@ def matmul_backend(backend: str = "xla", precision=None) -> Callable:
       * ``"pallas_fastmm"`` / ``"pallas_fastmm_interpret"`` — the fused
         chain with Strassen recursion per multiply (above the autotuned
         crossover); as a bare callable this is ``fastmm.strassen_matmul``.
+
+    The chain backends compute at :func:`precision_for` (``precision`` is
+    the ``xla`` backend's alone).
     """
     if backend == "xla":
         def mm(a, b):
@@ -83,10 +107,12 @@ def matmul_backend(backend: str = "xla", precision=None) -> Callable:
     if backend in _FAST_BACKENDS:
         from repro.kernels import fastmm as _fastmm
         return functools.partial(_fastmm.strassen_matmul,
-                                 interpret=_CHAIN_BACKENDS[backend])
+                                 interpret=_CHAIN_BACKENDS[backend],
+                                 precision=precision_for(backend))
     if backend in _CHAIN_BACKENDS:
         from repro.kernels import ops as kops
-        return functools.partial(kops.matmul, interpret=_CHAIN_BACKENDS[backend])
+        return functools.partial(kops.matmul, interpret=_CHAIN_BACKENDS[backend],
+                                 precision=precision_for(backend))
     raise ValueError(f"unknown matmul backend: {backend!r}")
 
 
@@ -105,7 +131,8 @@ def chain_for(a: jax.Array, backend: str, donate: bool = True):
     return kops.MatmulChain(a.shape[-1], a.dtype,
                             interpret=_CHAIN_BACKENDS[backend],
                             donate=donate,
-                            fast=backend in _FAST_BACKENDS)
+                            fast=backend in _FAST_BACKENDS,
+                            precision=precision_for(backend))
 
 
 def _accum_dtype(dtype) -> jnp.dtype:

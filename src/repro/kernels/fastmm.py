@@ -41,7 +41,7 @@ from repro.kernels import autotune
 from repro.kernels import ref as _ref
 
 __all__ = ["strassen_matmul", "strassen_square", "plan_levels",
-           "error_budget", "DENSE_BUDGET"]
+           "error_budget", "DENSE_BUDGET", "CHIP_PRODUCT_RTOL"]
 
 #: The dense routes' empirical vs-f64 tolerance floors (rtol, atol) per
 #: dtype name — the same values ``tests/test_chains_property.py`` has gated
@@ -52,6 +52,19 @@ DENSE_BUDGET = {
     "float32": (2e-3, 1e-5),
     "bfloat16": (0.15, 0.05),
 }
+
+#: What one f32 product is off by on a TPU v5e, per engine route: the
+#: largest entry error of a 1024 x 1024 product of uniform [0, 1)
+#: operands over the largest entry of the f64 product, read on the chip.
+#: The ``xla`` route runs at JAX's default, one bf16 pass per product
+#: (3.88e-4, XLA's dot and the Pallas kernel alike); ``chain`` and
+#: ``fastmm`` at ``core.matpow.CHAIN_PRECISION``, three passes: 7.4e-7 in
+#: the Pallas kernels, 7.7e-6 in Pade's XLA dots (bf16_3x), the larger
+#: stated. The f32 floor above holds where every product is exact to f32
+#: (the CPU, the Pallas interpreter at the default precision); a chip
+#: answer's error starts from its route's entry here and grows with the
+#: products it went through.
+CHIP_PRODUCT_RTOL = {"xla": 3.9e-4, "chain": 7.7e-6, "fastmm": 7.7e-6}
 
 
 def error_budget(dtype, *, levels: int = 0, n: int = 1,
@@ -131,21 +144,24 @@ def _strassen(a, b, levels: int, crossover: int, leaf: Callable):
     return c
 
 
-def _default_leaf(interpret: bool, leaf_blocks, out_dtype) -> Callable:
+def _default_leaf(interpret: bool, leaf_blocks, out_dtype,
+                  precision) -> Callable:
     # ops.matmul is the whole dispatch story in one call: tuned Pallas tiles
     # on TPU / interpret, fp32-accumulating XLA dot everywhere else, vmap
     # over leading batch dims. Lazy import: ops lazily imports this module
     # for the chain's fast path.
     from repro.kernels import ops as kops
     return functools.partial(kops.matmul, interpret=interpret,
-                             blocks=leaf_blocks, out_dtype=out_dtype)
+                             blocks=leaf_blocks, out_dtype=out_dtype,
+                             precision=precision)
 
 
 def strassen_matmul(a: jax.Array, b: jax.Array, *,
                     levels: Optional[int] = None,
                     crossover: Optional[int] = None,
                     leaf_blocks=None, interpret: bool = False,
-                    out_dtype=None, leaf: Optional[Callable] = None):
+                    out_dtype=None, leaf: Optional[Callable] = None,
+                    precision=None):
     """C = A @ B via Strassen recursion over the tuned dense leaves.
 
     Operands must be square with identical shapes (the squaring-chain
@@ -154,7 +170,8 @@ def strassen_matmul(a: jax.Array, b: jax.Array, *,
     default to the autotuned ``fastmm`` config for ``a.dtype``
     (``levels=0`` or ``crossover >= n`` degenerate to one dense leaf call).
     ``leaf`` overrides the dense leaf entirely (chain executors pass their
-    fixed-block ``mm``).
+    fixed-block ``mm``); ``precision`` is the default leaf's
+    (``matmul.tile_dot``).
     """
     if a.shape != b.shape or a.ndim < 2 or a.shape[-1] != a.shape[-2]:
         raise ValueError(f"strassen_matmul needs same-shape square "
@@ -163,7 +180,7 @@ def strassen_matmul(a: jax.Array, b: jax.Array, *,
     levels, crossover, leaf_blocks = _resolve(a.dtype, levels, crossover,
                                               leaf_blocks)
     if leaf is None:
-        leaf = _default_leaf(interpret, leaf_blocks, out_dtype)
+        leaf = _default_leaf(interpret, leaf_blocks, out_dtype, precision)
     return _strassen(a, b, levels, crossover, leaf)
 
 

@@ -26,6 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -40,7 +41,53 @@ __all__ = ["matmul_kernel", "matmul_pallas", "square_kernel",
 DEFAULT_BLOCK = (512, 512, 512)
 
 
-def matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
+def tile_dot(x: jax.Array, y: jax.Array, precision=None) -> jax.Array:
+    """x @ y on VMEM tiles, accumulated in f32, at ``precision``.
+
+    ``None`` is Mosaic's default: on a TPU v5e an f32 operand goes through
+    the MXU as one bf16 pass (8 significant bits per input). ``HIGHEST``
+    is Mosaic's f32 contract precision. Mosaic has no three-pass mode, so
+    ``HIGH`` is spelt out here as XLA's bf16_3x is: each f32 input split
+    into a bf16 head and the bf16 rounding of its remainder, and
+    x_hi y_hi + (x_hi y_lo + x_lo y_hi) summed in f32. The dropped
+    x_lo y_lo term and the remainders' rounding are each about 2**-16 of
+    the product, against 2**-9 for one pass.
+    """
+    if precision != lax.Precision.HIGH:
+        return jnp.dot(x, y, precision=precision,
+                       preferred_element_type=jnp.float32)
+
+    def split(z):
+        hi = z.astype(jnp.bfloat16)
+        return hi, (z - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    (x_hi, x_lo), (y_hi, y_lo) = split(x), split(y)
+
+    def dot(u, v):
+        return jnp.dot(u, v, preferred_element_type=jnp.float32)
+
+    return dot(x_hi, y_hi) + (dot(x_hi, y_lo) + dot(x_lo, y_hi))
+
+
+def kernel_precision(dtype, precision):
+    """The precision a kernel's tile products run at for ``dtype``
+    operands: ``precision`` for f32, Mosaic's default (None) otherwise.
+    Mosaic lowers an in-kernel dot at the default or ``HIGHEST`` only, and
+    a bf16 operand is exact in the default's one pass."""
+    return precision if jnp.dtype(dtype) == jnp.float32 else None
+
+
+def kernel_name(base: str, precision):
+    """The ``pallas_call`` name, which a TPU trace shows as the op's name:
+    ``base_<precision>`` (``matmul_pallas_high``) where a precision is
+    set; None at the default, which keeps Pallas's own name."""
+    if precision is None:
+        return None
+    return f"{base}_{lax.Precision(precision).name.lower()}"
+
+
+def matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype,
+                  precision=None):
     """Grid point (i, j, k): accumulate A[i,k]-tile @ B[k,j]-tile into acc."""
     k = pl.program_id(2)
 
@@ -49,9 +96,7 @@ def matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # MXU matmul on the VMEM-resident tiles; accumulate at fp32.
-    acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += tile_dot(a_ref[...], b_ref[...], precision)
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -68,7 +113,8 @@ def _accum_dtype(dtype) -> jnp.dtype:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype"),
+    static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype",
+                     "precision"),
 )
 def matmul_pallas(
     a: jax.Array,
@@ -79,8 +125,11 @@ def matmul_pallas(
     block_k: int = DEFAULT_BLOCK[2],
     interpret: bool = False,
     out_dtype=None,
+    precision=None,
 ) -> jax.Array:
-    """Block-divisible tiled matmul. See ``ops.matmul`` for arbitrary shapes."""
+    """Block-divisible tiled matmul. See ``ops.matmul`` for arbitrary shapes.
+    ``precision`` is each tile product's (:func:`tile_dot`), for f32
+    operands (:func:`kernel_precision`)."""
     if a.ndim != 2 or b.ndim != 2 or a.shape[1] != b.shape[0]:
         raise ValueError(f"bad matmul shapes {a.shape} @ {b.shape}")
     m, k = a.shape
@@ -90,12 +139,15 @@ def matmul_pallas(
             f"shapes ({m},{k})x({k},{n}) not divisible by blocks "
             f"({block_m},{block_n},{block_k}); use ops.matmul")
     out_dtype = out_dtype or a.dtype
+    precision = kernel_precision(jnp.promote_types(a.dtype, b.dtype),
+                                 precision)
     n_k = k // block_k
 
     grid = (m // block_m, n // block_n, n_k)
 
     return pl.pallas_call(
-        functools.partial(matmul_kernel, n_k=n_k, out_dtype=out_dtype),
+        functools.partial(matmul_kernel, n_k=n_k, out_dtype=out_dtype,
+                          precision=precision),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
@@ -109,6 +161,7 @@ def matmul_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=kernel_name("matmul_pallas", precision),
     )(a, b)
 
 
@@ -152,7 +205,8 @@ def square_tier(operand_bytes: int, vmem_limit: int = SQUARE_VMEM_LIMIT,
     return "two_operand"
 
 
-def square_kernel(a_ref, o_ref, *, block_m: int, block_n: int, out_dtype):
+def square_kernel(a_ref, o_ref, *, block_m: int, block_n: int, out_dtype,
+                  precision=None):
     """Grid point (i, j): C tile (i, j) of A @ A from ONE staged copy of A.
 
     The generic kernel streams two operand tiles per grid step; for the
@@ -166,12 +220,11 @@ def square_kernel(a_ref, o_ref, *, block_m: int, block_n: int, out_dtype):
     j = pl.program_id(1)
     row = a_ref[pl.ds(i * block_m, block_m), :]
     col = a_ref[:, pl.ds(j * block_n, block_n)]
-    o_ref[...] = jnp.dot(
-        row, col, preferred_element_type=jnp.float32
-    ).astype(out_dtype)
+    o_ref[...] = tile_dot(row, col, precision).astype(out_dtype)
 
 
-def square_panel_kernel(row_ref, col_ref, o_ref, *, out_dtype):
+def square_panel_kernel(row_ref, col_ref, o_ref, *, out_dtype,
+                        precision=None):
     """Grid point (i, j): C tile (i, j) of A @ A from VMEM-resident K-panels.
 
     The middle memory tier between the whole-operand ``square_kernel`` and
@@ -184,15 +237,14 @@ def square_panel_kernel(row_ref, col_ref, o_ref, *, out_dtype):
     traffic drops from 2 tile-reads per grid step to one panel-read per
     output tile plus one panel-read per output row.
     """
-    o_ref[...] = jnp.dot(
-        row_ref[...], col_ref[...], preferred_element_type=jnp.float32
-    ).astype(out_dtype)
+    o_ref[...] = tile_dot(row_ref[...], col_ref[...],
+                          precision).astype(out_dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype",
-                     "vmem_limit", "panel_limit"),
+                     "vmem_limit", "panel_limit", "precision"),
 )
 def square_pallas(
     a: jax.Array,
@@ -204,8 +256,11 @@ def square_pallas(
     out_dtype=None,
     vmem_limit: int = SQUARE_VMEM_LIMIT,
     panel_limit: int = SQUARE_PANEL_LIMIT,
+    precision=None,
 ) -> jax.Array:
     """C = A @ A for a block-divisible square A — the squaring-chain step.
+    ``precision`` is each tile product's (:func:`tile_dot`), for an f32
+    operand (:func:`kernel_precision`).
 
     Kernel choice follows the ``square_tier`` memory policy on the operand's
     byte size: the whole-operand single-ref kernel below ``vmem_limit``, the
@@ -224,6 +279,7 @@ def square_pallas(
         raise ValueError(f"square_pallas needs a square 2-D matrix, got {a.shape}")
     p = a.shape[0]
     out_dtype = out_dtype or a.dtype
+    precision = kernel_precision(a.dtype, precision)
     tier = square_tier(p * p * a.dtype.itemsize, vmem_limit, panel_limit)
     if tier == "panel" and panel_vmem_footprint(
             p, block_m, block_n, a.dtype.itemsize) > 2 * SQUARE_VMEM_LIMIT:
@@ -233,7 +289,7 @@ def square_pallas(
     if tier == "two_operand":
         return matmul_pallas(a, a, block_m=block_m, block_n=block_n,
                              block_k=block_k, interpret=interpret,
-                             out_dtype=out_dtype)
+                             out_dtype=out_dtype, precision=precision)
     if p % block_m or p % block_n:
         raise ValueError(
             f"shape ({p},{p}) not divisible by blocks ({block_m},{block_n}); "
@@ -252,18 +308,20 @@ def square_pallas(
     if tier == "whole":
         return pl.pallas_call(
             functools.partial(square_kernel, block_m=block_m, block_n=block_n,
-                              out_dtype=out_dtype),
+                              out_dtype=out_dtype, precision=precision),
             grid=grid,
             in_specs=[pl.BlockSpec((p, p), lambda i, j: (0, 0))],
             out_specs=out_spec,
             out_shape=out_shape,
             compiler_params=params,
             interpret=interpret,
+            name=kernel_name("square_pallas", precision),
         )(a)
 
     # Panel tier: the same array twice, viewed as row and column K-panels.
     return pl.pallas_call(
-        functools.partial(square_panel_kernel, out_dtype=out_dtype),
+        functools.partial(square_panel_kernel, out_dtype=out_dtype,
+                          precision=precision),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, p), lambda i, j: (i, 0)),
@@ -273,4 +331,5 @@ def square_pallas(
         out_shape=out_shape,
         compiler_params=params,
         interpret=interpret,
+        name=kernel_name("square_panel", precision),
     )(a, a)

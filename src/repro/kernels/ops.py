@@ -208,31 +208,30 @@ def pad_to_blocks(a: jax.Array, block_m: int, block_n: int) -> jax.Array:
 
 
 def matmul(a: jax.Array, b: jax.Array, *, interpret: bool = False,
-           blocks=None, out_dtype=None) -> jax.Array:
+           blocks=None, out_dtype=None, precision=None) -> jax.Array:
     """C = A @ B via the tiled Pallas kernel; arbitrary shapes and batching.
 
     a: (..., M, K), b: (..., K, N) (leading dims broadcast like jnp.matmul
     as long as they match exactly or are absent on one side).
+    ``precision`` is each product's (``matmul.tile_dot``; XLA's on the
+    portable path).
     """
     out_dtype = out_dtype or a.dtype
     if not (interpret or pallas_supported()):
         # Portable path: identical math (fp32 accumulation) via XLA.
-        return _ref.matmul_ref(a, b, out_dtype=out_dtype)
+        return _ref.matmul_ref(a, b, out_dtype=out_dtype,
+                               precision=precision)
 
     # Normalize batching: strip matching leading dims via vmap.
     if a.ndim > 2 or b.ndim > 2:
+        again = functools.partial(matmul, interpret=interpret, blocks=blocks,
+                                  out_dtype=out_dtype, precision=precision)
         if a.ndim == b.ndim:
-            return jax.vmap(lambda x, y: matmul(
-                x, y, interpret=interpret, blocks=blocks,
-                out_dtype=out_dtype))(a, b)
+            return jax.vmap(again)(a, b)
         if a.ndim > 2 and b.ndim == 2:
-            return jax.vmap(lambda x: matmul(
-                x, b, interpret=interpret, blocks=blocks,
-                out_dtype=out_dtype))(a)
+            return jax.vmap(lambda x: again(x, b))(a)
         if b.ndim > 2 and a.ndim == 2:
-            return jax.vmap(lambda y: matmul(
-                a, y, interpret=interpret, blocks=blocks,
-                out_dtype=out_dtype), out_axes=0)(b)
+            return jax.vmap(lambda y: again(a, y), out_axes=0)(b)
         raise ValueError(f"unsupported batch ranks {a.shape} @ {b.shape}")
 
     m, k = a.shape
@@ -243,7 +242,8 @@ def matmul(a: jax.Array, b: jax.Array, *, interpret: bool = False,
     b = pad_to_blocks(b, bk, bn)
 
     out = matmul_pallas(a, b, block_m=bm, block_n=bn, block_k=bk,
-                        interpret=interpret, out_dtype=out_dtype)
+                        interpret=interpret, out_dtype=out_dtype,
+                        precision=precision)
     if out.shape != (m, n):
         out = out[:m, :n]
     return out
@@ -294,19 +294,21 @@ def square(a: jax.Array, *, interpret: bool = False, blocks=None,
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype",
-                     "vmem_limit", "panel_limit"),
+                     "vmem_limit", "panel_limit", "precision"),
     donate_argnums=(0,),
 )
 def _square_step(a, *, block_m, block_n, block_k, interpret, out_dtype,
-                 vmem_limit, panel_limit):
+                 vmem_limit, panel_limit, precision):
     return square_pallas(a, block_m=block_m, block_n=block_n, block_k=block_k,
                          interpret=interpret, out_dtype=out_dtype,
-                         vmem_limit=vmem_limit, panel_limit=panel_limit)
+                         vmem_limit=vmem_limit, panel_limit=panel_limit,
+                         precision=precision)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _square_step_ref(a):
-    return _ref.matmul_ref(a, a)
+@functools.partial(jax.jit, static_argnames=("precision",),
+                   donate_argnums=(0,))
+def _square_step_ref(a, *, precision):
+    return _ref.matmul_ref(a, a, precision=precision)
 
 
 # Donated Strassen squaring step (the chain's fast=True path, eager calls
@@ -316,15 +318,16 @@ def _square_step_ref(a):
 @functools.partial(
     jax.jit,
     static_argnames=("levels", "crossover", "leaf_blocks", "interpret",
-                     "out_dtype"),
+                     "out_dtype", "precision"),
     donate_argnums=(0,),
 )
 def _fast_square_step(a, *, levels, crossover, leaf_blocks, interpret,
-                      out_dtype):
+                      out_dtype, precision):
     from repro.kernels import fastmm as _fastmm
     return _fastmm.strassen_square(a, levels=levels, crossover=crossover,
                                    leaf_blocks=leaf_blocks,
-                                   interpret=interpret, out_dtype=out_dtype)
+                                   interpret=interpret, out_dtype=out_dtype,
+                                   precision=precision)
 
 
 class PaddedChain:
@@ -409,12 +412,18 @@ class MatmulChain(PaddedChain):
     bit-exact contract — Strassen results are tolerance-bounded, not
     bit-identical (~1 bit per recursion level; see
     ``fastmm.error_budget``).
+
+    ``precision`` is every multiply's, Strassen's leaves included
+    (``matmul.tile_dot``; XLA's where the chain degrades to the XLA dot).
+    None is JAX's default: one bf16 pass per f32 product on a TPU v5e.
     """
 
     def __init__(self, n: int, dtype, *, interpret: bool = False,
-                 blocks=None, donate: bool = True, fast=False):
+                 blocks=None, donate: bool = True, fast=False,
+                 precision=None):
         super().__init__(n, dtype, donate=donate)
         self.interpret = bool(interpret)
+        self.precision = precision
         self.active = self.interpret or pallas_supported()
         if self.active:
             self.blocks, self.padded_n = _square_blocks(self.n, self.dtype,
@@ -455,7 +464,8 @@ class MatmulChain(PaddedChain):
                                        crossover=crossover,
                                        leaf_blocks=leaf_blocks,
                                        interpret=self.interpret,
-                                       out_dtype=self.dtype)
+                                       out_dtype=self.dtype,
+                                       precision=self.precision)
 
     # -- chain body (operands already padded) ------------------------------
     def mm(self, x: jax.Array, y: jax.Array) -> jax.Array:
@@ -463,12 +473,14 @@ class MatmulChain(PaddedChain):
         if self.fast:
             return self._strassen_mm(x, y)
         if not self.active:
-            return _ref.matmul_ref(x, y, out_dtype=self.dtype)
+            return _ref.matmul_ref(x, y, out_dtype=self.dtype,
+                                   precision=self.precision)
         if x.ndim > 2 or y.ndim > 2:
             return jax.vmap(self.mm)(x, y)
         bm, bn, bk = self.blocks
         return matmul_pallas(x, y, block_m=bm, block_n=bn, block_k=bk,
-                             interpret=self.interpret, out_dtype=self.dtype)
+                             interpret=self.interpret, out_dtype=self.dtype,
+                             precision=self.precision)
 
     def square(self, x: jax.Array) -> jax.Array:
         """x @ x via the single-ref kernel; CONSUMES x (buffer donation).
@@ -486,12 +498,14 @@ class MatmulChain(PaddedChain):
                                          crossover=crossover,
                                          leaf_blocks=leaf_blocks,
                                          interpret=self.interpret,
-                                         out_dtype=self.dtype)
+                                         out_dtype=self.dtype,
+                                         precision=self.precision)
             return self._strassen_mm(x, x)
         if not self.active:
             if self.donate and eager:
-                return _square_step_ref(x)
-            return _ref.matmul_ref(x, x, out_dtype=self.dtype)
+                return _square_step_ref(x, precision=self.precision)
+            return _ref.matmul_ref(x, x, out_dtype=self.dtype,
+                                   precision=self.precision)
         if x.ndim > 2:
             return jax.vmap(self.square)(x)
         bm, bn, bk = self.blocks
@@ -500,10 +514,12 @@ class MatmulChain(PaddedChain):
             return _square_step(x, block_m=bm, block_n=bn, block_k=bk,
                                 interpret=self.interpret, out_dtype=self.dtype,
                                 vmem_limit=vmem_limit,
-                                panel_limit=panel_limit)
+                                panel_limit=panel_limit,
+                                precision=self.precision)
         return square_pallas(x, block_m=bm, block_n=bn, block_k=bk,
                              interpret=self.interpret, out_dtype=self.dtype,
-                             vmem_limit=vmem_limit, panel_limit=panel_limit)
+                             vmem_limit=vmem_limit, panel_limit=panel_limit,
+                             precision=self.precision)
 
 
 def attention(q, k, v, *, causal: bool = True, window=None, scale=None,

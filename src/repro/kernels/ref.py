@@ -14,11 +14,14 @@ import jax.numpy as jnp
 __all__ = ["matmul_ref", "matmul_naive_ref", "flash_attention_ref"]
 
 
-def matmul_ref(a: jax.Array, b: jax.Array, out_dtype=None) -> jax.Array:
-    """fp32-accumulating matmul oracle (the paper's sequential reference)."""
+def matmul_ref(a: jax.Array, b: jax.Array, out_dtype=None,
+               precision=None) -> jax.Array:
+    """fp32-accumulating matmul oracle (the paper's sequential reference),
+    at XLA's ``precision`` (None: JAX's default)."""
     out_dtype = out_dtype or a.dtype
     acc = jnp.dtype(jnp.float32) if jnp.dtype(a.dtype) != jnp.float64 else a.dtype
-    return jnp.matmul(a, b, preferred_element_type=acc).astype(out_dtype)
+    return jnp.matmul(a, b, preferred_element_type=acc,
+                      precision=precision).astype(out_dtype)
 
 
 def matmul_naive_ref(a, b):

@@ -1038,6 +1038,26 @@ class MatFnEngine:
     def _fastmm_backend(self) -> str:
         return "pallas_fastmm_interpret" if self.interpret else "pallas_fastmm"
 
+    def _route_backend(self, route: str) -> str:
+        """The core backend a local route runs its products on (the
+        evolve route's squarings; its members at n <= cpu_max_n and its
+        B-row products stay on the ``xla`` default)."""
+        return (self._chain_backend if route in ("chain", "evolve")
+                else self._fastmm_backend if route == "fastmm"
+                else "xla")
+
+    def route_precision(self, route: str) -> str:
+        """The dot precision a route's f32 products run at, by name: set
+        per backend in one place (``core.matpow.precision_for``);
+        ``default`` is JAX's (one bf16 pass per f32 product on a TPU v5e).
+        The ``sharded`` route's collective chain keeps the default, and so
+        do the Pallas kernels on bf16 operands
+        (``kernels.matmul.kernel_precision``)."""
+        from repro.core.matpow import precision_for
+        precision = None if route == "sharded" \
+            else precision_for(self._route_backend(route))
+        return "default" if precision is None else precision.name.lower()
+
     # -- executable cache --------------------------------------------------
     def _executable(self, op: str, route: str, padded_batch: int, n: int,
                     dtype: str, power: int):
@@ -1089,9 +1109,7 @@ class MatFnEngine:
     def _per_stack(self, op: str, route: str, n: int, dtype: str, power):
         """What a local route's bucket program runs on the stacked bucket:
         one program per (op, route), whatever the padded batch."""
-        backend = (self._chain_backend if route == "chain"
-                   else self._fastmm_backend if route == "fastmm"
-                   else "xla")
+        backend = self._route_backend(route)
         if op == "markov" and _is_evolve(power):
             # The evolve route: lax.map of each (operand, dists) pair
             # through the binary-decomposition vector-matrix chain, for
@@ -1103,7 +1121,7 @@ class MatFnEngine:
             cpu_max_n, _ = self.thresholds_for(dtype)
             per_member = functools.partial(
                 evolve_distributions, steps=power[1],
-                backend="xla" if n <= cpu_max_n else self._chain_backend,
+                backend="xla" if n <= cpu_max_n else backend,
                 validate=False)
             return lambda pairs: lax.map(
                 lambda pair: per_member(pair[1], pair[0]), pairs)
@@ -1224,7 +1242,9 @@ class MatFnEngine:
                         padded=bpad)
             assemble = tracer.span("bucket.assemble", track=track, **tags)
             execute = tracer.span("bucket.execute", track=track,
-                                  profiled=self.profile, **tags)
+                                  profiled=self.profile,
+                                  precision=self.route_precision(route),
+                                  **tags)
         else:
             tags = None
             assemble = execute = NULL_SPAN
@@ -1951,6 +1971,8 @@ class MatFnEngine:
                     "stragglers": self.stats["stragglers"],
                     "retries": self.stats["retries"],
                     "routes": dict(self.stats["routes"]),
+                    "precision": {r: self.route_precision(r)
+                                  for r in ROUTES},
                     "flush_triggers": dict(self.stats["flush_triggers"]),
                     "lanes": lanes,
                     "open_buckets": len(self._open_buckets),

@@ -17,6 +17,11 @@ through :func:`assert_within_budget` so the budget lives in ONE place
 (``fastmm.DENSE_BUDGET`` + ``fastmm.error_budget``) instead of sprinkled
 rtol literals; bit-exact assertions go through :func:`assert_bit_identical`
 so a route silently drifting into "merely close" fails loudly.
+
+Those floors are the CPU's, where every f32 product is exact to f32. On a
+TPU v5e a product is off by its route's precision: one bf16 pass on the
+``xla`` route, three on ``chain`` and ``fastmm``
+(``fastmm.CHIP_PRODUCT_RTOL``, the per-product error a chip reads).
 """
 
 import numpy as np

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import (expm, matpow_binary, matpow_binary_traced,
                         matpow_naive)
+from repro.core import matpow as core_matpow
 from repro.kernels import ops, ref
 from repro.kernels.matmul import square_pallas
 
@@ -91,13 +92,40 @@ class TestChainNumerics:
         np.testing.assert_allclose(np.asarray(got), _ref_pow(a, 9),
                                    rtol=1e-3, atol=1e-5)
 
-    def test_expm_chain_matches_xla(self):
+    def test_expm_chain_matches_xla(self, monkeypatch):
+        # At equal precision: the chain's products otherwise run at
+        # matpow.CHAIN_PRECISION (three bf16 passes, which the interpreter
+        # carries out exactly), the xla route's at JAX's default (exact f32
+        # on a CPU). tests/test_tandem_fastmm.py holds the chain at its own
+        # precision against f64.
+        monkeypatch.setattr(core_matpow, "CHAIN_PRECISION", None)
         rng = np.random.default_rng(0)
         a = rng.standard_normal((96, 96)) * 0.2
         want = np.asarray(expm(jnp.asarray(a, jnp.float32)), np.float64)
         got = np.asarray(expm(jnp.asarray(a, jnp.float32), backend=CHAIN),
                          np.float64)
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+
+    def test_expm_chain_at_its_precision_matches_f64(self):
+        """The chain as it runs: every squaring at matpow.CHAIN_PRECISION
+        (three bf16 passes; the interpreter carries them out exactly),
+        against scipy's f64 expm of the same operand. A three-pass product
+        is off by about 2**-16 of its largest entry (x_lo y_lo dropped, the
+        remainders rounded), and each of the s squarings doubles what the
+        result already carries: budget 2 * 2**s * 2**-16 of the largest
+        entry. One bf16 pass (2**-9 a product, 128 times as much) cannot
+        meet it."""
+        import scipy.linalg
+        rng = np.random.default_rng(0)
+        a = jnp.asarray(rng.standard_normal((96, 96)) * 0.2, jnp.float32)
+        a64 = np.asarray(a, np.float64)
+        want = scipy.linalg.expm(a64)
+        s = int(np.ceil(np.log2(np.abs(a64).sum(axis=0).max()
+                                / 5.371920351148152)))
+        assert s == 2                       # the chain squares here
+        got = np.asarray(expm(a, backend=CHAIN), np.float64)
+        budget = 2 * 2.0 ** s * 2.0 ** -16 * np.abs(want).max()
+        assert np.abs(got - want).max() <= budget
 
 
 class TestSinglePadInvariant:

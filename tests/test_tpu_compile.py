@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -100,13 +101,12 @@ def test_matmul_pallas_4096_compiles(chip):
     assert _kernels(matmul_pallas, spec, spec) == 1
 
 
-def _engine_executable(op, n, power=1, batch=1):
+def _engine_executable(op, n, power=1, batch=1, dtype="float32"):
     """The jitted executable the serving engine builds for one bucket."""
     from repro.serve import MatFnEngine
     engine = MatFnEngine()
-    route = engine.route_for(n, batch, "float32", power)
-    _key, exe, _fresh = engine._executable(op, route, batch, n, "float32",
-                                           power)
+    route = engine.route_for(n, batch, dtype, power)
+    _key, exe, _fresh = engine._executable(op, route, batch, n, dtype, power)
     return route, exe
 
 
@@ -121,9 +121,68 @@ def test_engine_chain_executables_compile(chip, op, n, power, route):
     assert _kernels(exe, chip((n, n))) >= 1   # a bucket of one member
 
 
+@pytest.mark.parametrize("op,n,power,route,high", [
+    ("matpow", 1024, 64, "chain", False),
+    ("matpow", 4096, 64, "fastmm", False),
+    ("expm", 2048, -1, "fastmm", True),
+], ids=["chain-matpow-1024", "fastmm-matpow-4096", "fastmm-expm-2048"])
+def test_engine_bf16_executables_compile(chip, tmp_path, monkeypatch, op, n,
+                                         power, route, high):
+    """bfloat16 buckets on the chain routes. Mosaic lowers an in-kernel dot
+    at the default or the f32 contract precision only, so the chain
+    precision must not reach a bf16 kernel (a bf16 operand is exact in one
+    pass): matpow's bf16 kernels keep their default names. expm computes a
+    bf16 operand in f32, so its kernels run at the chain precision."""
+    from repro.core import matpow
+    from repro.kernels import autotune
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "tune.json"))
+    if op == "expm":
+        autotune.record_fastmm(1024, 1, dtype="bfloat16")
+    taken, exe = _engine_executable(op, n, power, dtype="bfloat16")
+    assert taken == route
+    text = exe.lower(chip((n, n), jnp.bfloat16)).compile().as_text()
+    kernels = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\([^\n]*"
+                         r"tpu_custom_call", text)
+    suffix = "_" + matpow.CHAIN_PRECISION.name.lower()
+    assert kernels
+    assert all(k.endswith(suffix) == high for k in kernels)
+
+
 def test_evolve_distributions_2048_compiles(chip):
     from repro.core.markov import evolve_distributions
     fn = functools.partial(evolve_distributions, steps=1000,
                            backend="pallas_chain", validate=False)
     assert _kernels(lambda d, p: fn(d, p), chip((64, 2048)),
                     chip((2048, 2048))) >= 1
+
+
+@pytest.mark.parametrize("route,n", [("xla", 61), ("chain", 256),
+                                     ("fastmm", 256)])
+def test_engine_expm_executables_hold_their_route_precision(
+        chip, tmp_path, monkeypatch, route, n):
+    """The chain and fastmm routes run every product at the chain
+    precision: Pade's XLA dots carry it (``operand_precision``) and the
+    Pallas kernels are its three-pass bodies (named for it). The ``xla``
+    route's products keep the default: no precision on Pade's dots."""
+    from repro.core import matpow
+    from repro.kernels import autotune
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "tune.json"))
+    if route == "fastmm":
+        autotune.record_fastmm(128, 1)  # n = 256: one Strassen level
+    taken, exe = _engine_executable("expm", n, power=-1)
+    assert taken == route
+    text = exe.lower(chip((n, n))).compile().as_text()
+    pade = [line for line in text.splitlines()
+            if "expm.pade" in line and ("convolution(" in line
+                                        or " dot(" in line)]
+    assert len(pade) >= 6
+    name = matpow.CHAIN_PRECISION.name.lower()
+    kernels = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\([^\n]*"
+                         r"tpu_custom_call", text)
+    if route == "xla":
+        assert not any("operand_precision" in line for line in pade)
+        assert kernels == []
+    else:
+        assert all(f"operand_precision={{{name},{name}}}" in line
+                   for line in pade)
+        assert kernels and all(k.endswith("_" + name) for k in kernels)
