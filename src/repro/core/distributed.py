@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Optional
 
 import jax
@@ -140,10 +141,6 @@ def matmul_cannon(a: jax.Array, b: jax.Array, mesh: Mesh, *,
 
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
                          out_specs=spec, check_vma=False)(a, b)
-
-
-def _log2(x: int) -> int:
-    return x.bit_length() - 1
 
 
 def _pick_algorithm(algorithm: str, rows: int, cols: int) -> str:
@@ -381,7 +378,7 @@ def expm_sharded(a: jax.Array, mesh: Mesh, *, max_squarings: int = 32,
     """Matrix exponential e^A with A 2-D-sharded — the scientific workload
     at mesh scale.
 
-    Same scaling-and-squaring structure as :func:`repro.core.expm.expm`
+    The scaling-and-squaring body of :func:`repro.core.expm.expm`
     (Pade-13 + data-dependent squarings), with the squaring chain routed
     through :class:`ShardedMatmulChain`: the Pade result is padded and
     committed to its 2-D sharding ONCE, then squared ``s`` times inside a
@@ -401,35 +398,15 @@ def expm_sharded(a: jax.Array, mesh: Mesh, *, max_squarings: int = 32,
     """
     # Deferred: repro.core.expm imports repro.core.matpow at module load;
     # importing it lazily keeps distributed importable on its own.
-    from repro.core.expm import _pade13, _THETA13
+    from repro.core.expm import _scaling_and_squaring
 
     if a.ndim != 2 or a.shape[-1] != a.shape[-2] or a.shape[-1] < 1:
         raise ValueError(f"expm_sharded needs one square matrix with n >= 1, "
                          f"got {a.shape}")
-    dtype = a.dtype
-    compute = a.astype(jnp.float64 if dtype == jnp.float64 else jnp.float32)
-
-    norm = jnp.linalg.norm(compute, ord=1, axis=(-2, -1), keepdims=True)
-    s = jnp.maximum(0.0, jnp.ceil(jnp.log2(norm / _THETA13)))
-    s = jnp.minimum(s, float(max_squarings)).astype(jnp.int32)
-    scaled = compute / (2.0 ** s.astype(compute.dtype))
-
-    ident = jnp.eye(a.shape[-1], dtype=compute.dtype)
-    u, v = _pade13(scaled, ident)
-    r = jnp.linalg.solve(v - u, v + u)
-
     # Squarings always run inside the fori_loop (traced) — donation never
     # fires, so skip the donate-enabled chain's defensive pad-time copy.
-    chain = ShardedMatmulChain(a.shape[-1], compute.dtype, mesh,
-                               algorithm=algorithm, row_axis=row_axis,
-                               col_axis=col_axis, donate=False)
-    r = chain.pad(r)
-
-    def body(i, r_cur):
-        sq = chain.square(r_cur)
-        # jnp.where, NOT multiply-masking: a masked squaring that overflows
-        # to inf would turn 0 * inf into NaN (mirrors core/expm.py's fix).
-        return jnp.where(i < s, sq, r_cur)
-
-    r = lax.fori_loop(0, jnp.max(s), body, r)
-    return chain.unpad(r).astype(dtype)
+    return _scaling_and_squaring(
+        a, max_squarings, operator.matmul,
+        lambda r: ShardedMatmulChain(r.shape[-1], r.dtype, mesh,
+                                     algorithm=algorithm, row_axis=row_axis,
+                                     col_axis=col_axis, donate=False))

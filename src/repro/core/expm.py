@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import operator
+import types
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +70,33 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
         raise ValueError(f"expm needs square matrices, got {a.shape}")
     if a.shape[-1] < 1:
         raise ValueError(f"expm needs matrices with n >= 1, got {a.shape}")
+    return _scaling_and_squaring(
+        a, max_squarings,
+        functools.partial(jnp.matmul, precision=matpow.precision_for(backend)),
+        functools.partial(_squaring_chain, backend=backend))
+
+
+def _squaring_chain(r: jax.Array, backend: str):
+    """The chain that squares ``r`` on one device: ``backend``'s fused
+    chain, else its per-call product on the unpadded buffer."""
+    # Squarings run inside the fori_loop (always traced) — donation never
+    # fires, so skip the donate-enabled chain's defensive pad-time copy.
+    chain = matpow.chain_for(r, backend, donate=False)
+    if chain is None:
+        mm = operator.matmul if backend == "xla" \
+            else matpow.matmul_backend(backend)
+        chain = types.SimpleNamespace(pad=lambda x: x, unpad=lambda x: x,
+                                      square=lambda x: mm(x, x))
+    return chain
+
+
+def _scaling_and_squaring(a: jax.Array, max_squarings: int, mm,
+                          squaring_chain) -> jax.Array:
+    """e^a by scaling and squaring, for :func:`expm` and
+    :func:`repro.core.distributed.expm_sharded` alike: scale by the
+    1-norm, Pade-13 through ``mm``, one solve, then the masked squarings
+    on ``squaring_chain(r)`` (an object with ``pad``, ``square`` and
+    ``unpad``) between one pad and one unpad."""
     dtype = a.dtype
     compute = a.astype(jnp.float64 if dtype == jnp.float64 else jnp.float32)
 
@@ -85,31 +113,18 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
         scaled = compute / (2.0 ** s.astype(compute.dtype))
 
     ident = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=compute.dtype), compute.shape)
-    mm = functools.partial(jnp.matmul,
-                           precision=matpow.precision_for(backend))
     with jax.named_scope("expm.pade"):
         u, v = _pade13(scaled, ident, mm)
     with jax.named_scope("expm.solve"):
         # r = (v - u)^-1 (v + u)
         r = jnp.linalg.solve(v - u, v + u)
 
-    # Squarings run inside the fori_loop (always traced) — donation never
-    # fires, so skip the donate-enabled chain's defensive pad-time copy.
-    chain = matpow.chain_for(r, backend, donate=False)
-    if chain is not None:
-        square = chain.square
-        r = chain.pad(r)
-    elif backend == "xla":
-        square = lambda x: x @ x
-    else:
-        mm = matpow.matmul_backend(backend)
-        square = lambda x: mm(x, x)
-
+    chain = squaring_chain(r)
+    r = chain.pad(r)
     s_scalar = jnp.max(s)  # batched: square to the max, masking finished ones
 
-    def body(i, val):
-        r_cur = val
-        sq = square(r_cur)
+    def body(i, r_cur):
+        sq = chain.square(r_cur)
         # jnp.where, NOT multiply-masking: a finished member's wasted extra
         # squaring can overflow to inf in fp32, and 0 * inf = NaN would
         # corrupt its already-correct result. (i < s) broadcasts (..., 1, 1).
@@ -117,6 +132,4 @@ def expm(a: jax.Array, *, max_squarings: int = 32,
 
     with jax.named_scope("expm.square"):
         r = lax.fori_loop(0, s_scalar, body, r)
-    if chain is not None:
-        r = chain.unpad(r)
-    return r.astype(dtype)
+    return chain.unpad(r).astype(dtype)

@@ -45,8 +45,8 @@ that pipeline as a service layer over the reproduction's chain executors:
   * **Execution streams** (:mod:`repro.serve.streams`): the daemon's
     scheduler thread keeps admission, bucketing, deadlines, and lane
     priority to itself, but hands each due bucket to its dispatch route's
-    execution stream — a route-keyed worker pool (one stream each for
-    ``xla`` / ``chain`` / ``sharded`` by default; configurable via
+    execution stream — a route-keyed worker pool (one stream per route
+    by default; configurable via
     :class:`~repro.serve.streams.ExecutionStreams`) — so an in-flight
     chain bucket no longer blocks a due xla or priority-lane flush.
     Streams change the SCHEDULE, never the math (``streams=1`` collapses
@@ -112,15 +112,18 @@ import threading
 import time
 from concurrent.futures import CancelledError, InvalidStateError
 from concurrent.futures import TimeoutError as FutureTimeoutError
-from typing import List, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import distributed
 from repro.core.batched import batched_matpow
 from repro.core.expm import expm as _expm
+from repro.core.markov import evolve_distributions, steady_state
+from repro.core.matpow import precision_for
 from repro.kernels import autotune
 from repro.runtime.fault import Watchdog, retry_step
 from repro.runtime.telemetry import (NULL_SPAN, NULL_TRACER,
@@ -129,31 +132,126 @@ from repro.serve.admission import (LANES, AdmissionControl, PendingView,
                                    ShedError)
 from repro.serve.scheduler import (BucketView, FillOrDeadline, FlushPolicy,
                                    SystemClock)
-from repro.serve.streams import ExecutionStreams, StreamCrashed, StreamPool
+from repro.serve.streams import (DEFAULT_ROUTES, ExecutionStreams,
+                                 StreamCrashed, StreamPool)
 
 __all__ = ["MatFnRequest", "MatFnEngine", "MatFnFuture",
            "BucketExecutionError", "ShedError", "bucket_batch",
            "ExecutionStreams", "OPS", "ROUTES", "TRIGGERS"]
 
+#: Dispatch routes a bucket can take (see :meth:`MatFnEngine.route_for`):
+#: the one tuple :data:`repro.serve.streams.DEFAULT_ROUTES`.
+ROUTES = DEFAULT_ROUTES
+
+
+class _Route(NamedTuple):
+    """A route-table row: the core backend of a local route's products
+    (None for ``sharded``'s mesh chain) and their precision's name."""
+    backend: Optional[str]
+    precision: str
+
+
+# -- the op table: one entry per traffic class -----------------------------
+def _check_power(req) -> None:
+    if not isinstance(req.power, int) or isinstance(req.power, bool):
+        raise TypeError(f"{req.op} requests need a static python "
+                        f"int power (one executable per power)")
+    if req.power < 0:
+        raise ValueError("negative powers not supported")
+
+
+def _check_evolve(req) -> None:
+    _check_power(req)
+    a, d = req.operand, req.dists
+    if d.ndim != 2 or d.shape[0] < 1 or d.shape[1] != a.shape[0]:
+        raise ValueError(f"dists must be a (B, n) stack matching "
+                         f"the (n, n) operand, got dists shape "
+                         f"{d.shape} for n = {a.shape[0]}")
+    if d.dtype != a.dtype:
+        raise ValueError(f"dists dtype {d.dtype.name} must match "
+                         f"operand dtype {a.dtype.name}")
+
+
+def _stack_map(per_member):
+    return lambda stack: lax.map(per_member, stack)
+
+
+def _steady_sharded(mesh, n: int, dtype, power):
+    # The convergence loop on a ShardedMatmulChain (pad and 2-D sharding
+    # committed once, every squaring a collective step), like expm_sharded.
+    chain = distributed.ShardedMatmulChain(n, dtype, mesh, donate=False)
+    return lambda x: steady_state(x, validate=False, chain=chain)
+
+
+def _evolve_local(backend: str, power, small: bool):
+    # Each member's big-B dense fallback (the autotuned ``markov``
+    # threshold, resolved at trace time) is per shape; members at
+    # n <= cpu_max_n run on ``xla``.
+    per_member = functools.partial(evolve_distributions, steps=power[1],
+                                   backend="xla" if small else backend,
+                                   validate=False)
+    return _stack_map(lambda pair: per_member(pair[1], pair[0]))
+
+
+@dataclasses.dataclass(frozen=True)
+class _OpClass:
+    """Everything the engine knows of one traffic class; it tests no op
+    by name outside this table, so a new op is one entry and its core
+    function. A request finds its entry by ``(op, dists)``; a bucket key
+    by its op, or by the class a tuple power slot names first."""
+    op: str                       # submit's op name
+    dists: bool                   # requests carry a (B, n) dists stack
+    slot: Callable                # (power, dists) -> the key's power slot
+    # (backend, slot, n <= cpu_max_n) -> program over the stacked bucket.
+    # The per-matrix members run under lax.map, never as one stacked
+    # program: each member lowers as its own 2-D program, so answers stay
+    # bit-identical to per-matrix calls (a fused batched expm reassociates
+    # the Pade chain and drifts ~1 ulp at B > 1), and each keeps its own
+    # data-dependent squaring count instead of the stack's slowest.
+    local: Callable
+    sharded: Optional[Callable]   # (mesh, n, dtype, slot) -> one-matrix
+    validate: Callable = lambda req: None   # raises on a bad payload
+    filler: Callable = lambda n, dtype, slot: jnp.zeros((n, n), dtype)
+    route: Optional[str] = None   # a route fixed whatever n and batch
+
+
+_OP_TABLE = {
+    "matpow": _OpClass(
+        "matpow", False, lambda power, dists: power,
+        lambda backend, power, small: functools.partial(
+            batched_matpow, p=power, backend=backend),
+        lambda mesh, n, dtype, power: functools.partial(
+            distributed.matpow_sharded, n=power, mesh=mesh),
+        validate=_check_power),
+    "expm": _OpClass(
+        "expm", False, lambda power, dists: -1,
+        lambda backend, power, small: _stack_map(
+            functools.partial(_expm, backend=backend)),
+        lambda mesh, n, dtype, power: functools.partial(
+            distributed.expm_sharded, mesh=mesh)),
+    "markov": _OpClass(              # steady state
+        "markov", False, lambda power, dists: -1,
+        lambda backend, power, small: _stack_map(functools.partial(
+            steady_state, validate=False, backend=backend)),
+        _steady_sharded),
+    # The horizon and distribution count are executable-shape parameters,
+    # so they key the class like a matpow power; vector-matrix work has a
+    # route and stream of its own.
+    "evolve": _OpClass(
+        "markov", True, lambda power, dists: ("evolve", power, dists.shape[0]),
+        _evolve_local, None, validate=_check_evolve, route="evolve",
+        filler=lambda n, dtype, slot: (jnp.zeros((n, n), dtype),
+                                       jnp.zeros((slot[2], n), dtype))),
+}
+_REQUEST_CLASSES = {(c.op, c.dists): c for c in _OP_TABLE.values()}
+
 #: Ops the engine serves.
-OPS = ("matpow", "expm", "markov")
-
-#: Dispatch routes a bucket can take (see :meth:`MatFnEngine.route_for`).
-#: ``xla``/``chain``/``sharded`` are bit-identical to per-matrix calls of
-#: the same kernels; ``fastmm`` (Strassen recursion above the autotuned
-#: crossover) is tolerance-bounded — see ``kernels.fastmm.error_budget``.
-#: ``evolve`` serves markov distribution-evolution buckets — (B, n)
-#: vector-matrix chains through the tuned dense tiles, an entirely
-#: different (much cheaper) kernel shape from the dense-square routes.
-ROUTES = ("xla", "chain", "sharded", "fastmm", "evolve")
+OPS = tuple(dict.fromkeys(c.op for c in _OP_TABLE.values()))
 
 
-def _is_evolve(power) -> bool:
-    """True for the evolve bucket power slot ``("evolve", steps, B)`` —
-    the markov distribution-evolution traffic class (steady-state markov
-    buckets use the scalar -1 slot like expm)."""
-    return isinstance(power, tuple) and len(power) == 3 \
-        and power[0] == "evolve"
+def _op_class(op: Optional[str], power) -> Optional[_OpClass]:
+    return _OP_TABLE.get(power[0] if isinstance(power, tuple) else op)
+
 
 #: Flush triggers the daemon distinguishes in ``stats["flush_triggers"]``
 #: (``priority`` = a latency-lane request at n >= bypass_n forced its
@@ -307,26 +405,14 @@ class MatFnRequest:
         if a.ndim != 2 or a.shape[0] != a.shape[1] or a.shape[0] < 1:
             raise ValueError(f"{self.op} requests need one (n, n) matrix "
                              f"with n >= 1, got shape {a.shape}")
-        if self.dists is not None and self.op != "markov":
+        if self._class is None:
             raise ValueError(f"dists is only meaningful for op='markov', "
                              f"got op={self.op!r}")
-        if self.op == "matpow" or (self.op == "markov"
-                                   and self.dists is not None):
-            if not isinstance(self.power, int) \
-                    or isinstance(self.power, bool):
-                raise TypeError(f"{self.op} requests need a static python "
-                                f"int power (one executable per power)")
-            if self.power < 0:
-                raise ValueError("negative powers not supported")
-        if self.dists is not None:
-            d = self.dists
-            if d.ndim != 2 or d.shape[0] < 1 or d.shape[1] != a.shape[0]:
-                raise ValueError(f"dists must be a (B, n) stack matching "
-                                 f"the (n, n) operand, got dists shape "
-                                 f"{d.shape} for n = {a.shape[0]}")
-            if d.dtype != a.dtype:
-                raise ValueError(f"dists dtype {d.dtype.name} must match "
-                                 f"operand dtype {a.dtype.name}")
+        self._class.validate(self)
+
+    @property
+    def _class(self) -> Optional[_OpClass]:
+        return _REQUEST_CLASSES.get((self.op, self.dists is not None))
 
     @property
     def n(self) -> int:
@@ -340,19 +426,11 @@ class MatFnRequest:
             else (self.operand, self.dists)
 
     def bucket_key(self) -> tuple:
-        """(op, n, dtype, power) — the group this request batches with.
-        expm and markov steady-state have no power, so every such request
-        of one (n, dtype) shares a bucket (power slot -1); markov evolve
-        requests carry ``("evolve", steps, B)`` in the power slot — the
-        horizon and distribution count are executable-shape parameters,
-        so they key the traffic class like a matpow power does."""
-        if self.op == "matpow":
-            power = self.power
-        elif self.op == "markov" and self.dists is not None:
-            power = ("evolve", self.power, self.dists.shape[0])
-        else:
-            power = -1
-        return (self.op, self.n, self.operand.dtype.name, power)
+        """(op, n, dtype, power) — the group this request batches with;
+        its op-table entry fills the power slot (-1 where the class has
+        no power, ``("evolve", steps, B)`` for markov evolve)."""
+        return (self.op, self.n, self.operand.dtype.name,
+                self._class.slot(self.power, self.dists))
 
 
 @dataclasses.dataclass
@@ -413,6 +491,18 @@ def _bucket_program(per_stack):
                      for j in range(len(members)))
 
     return jax.jit(program)
+
+
+def _canonical(x):
+    """``x`` as-is when it is a jax array or a canonical-dtype numpy
+    array, else ``jnp.asarray`` of it in its canonical dtype."""
+    if not isinstance(x, (jax.Array, np.ndarray)):
+        return jnp.asarray(x)
+    if isinstance(x, np.ndarray):
+        canon = jax.dtypes.canonicalize_dtype(x.dtype)
+        if canon != x.dtype:
+            return jnp.asarray(x, canon)
+    return x
 
 
 def bucket_batch(b: int, max_batch: int = 64) -> int:
@@ -633,6 +723,17 @@ class MatFnEngine:
         self.retries = int(retries)
         self.retry_backoff_s = float(retry_backoff_s)
         self._streams = streams if streams is not None else ExecutionStreams()
+        # The route table: each route's core backend and the name of its
+        # products' precision. The sharded route's mesh chain runs at
+        # JAX's default precision.
+        chain, fastmm = (f"pallas_{k}_interpret" if self.interpret
+                         else f"pallas_{k}" for k in ("chain", "fastmm"))
+        self._routes = {
+            route: _Route(backend, getattr(precision_for(backend), "name",
+                                           "default").lower())
+            for route, backend in (("xla", "xla"), ("chain", chain),
+                                   ("fastmm", fastmm), ("evolve", chain))}
+        self._routes["sharded"] = _Route(None, "default")
         missing = [r for r in ROUTES if r not in self._streams.routes]
         if missing:
             raise ValueError(
@@ -766,20 +867,8 @@ class MatFnEngine:
         if priority not in LANES:
             raise ValueError(f"unknown priority lane {priority!r}; "
                              f"expected one of {LANES}")
-        if not isinstance(operand, (jax.Array, np.ndarray)):
-            operand = jnp.asarray(operand)
-        elif isinstance(operand, np.ndarray):
-            canon = jax.dtypes.canonicalize_dtype(operand.dtype)
-            if canon != operand.dtype:
-                operand = jnp.asarray(operand, canon)
-        if dists is not None:
-            if not isinstance(dists, (jax.Array, np.ndarray)):
-                dists = jnp.asarray(dists)
-            elif isinstance(dists, np.ndarray):
-                canon = jax.dtypes.canonicalize_dtype(dists.dtype)
-                if canon != dists.dtype:
-                    dists = jnp.asarray(dists, canon)
-        req = MatFnRequest(op, operand, power, dists)
+        req = MatFnRequest(op, _canonical(operand), power,
+                           None if dists is None else _canonical(dists))
         # Mode check under the lock: a concurrent start() must never see
         # _pending empty and then have a sync request appended behind its
         # back — that ticket could never resolve (the daemon only serves
@@ -962,8 +1051,7 @@ class MatFnEngine:
         key = jnp.dtype(dtype).name if dtype is not None else "any"
         return self._memoized(
             self._thresholds_cache, key,
-            lambda: autotune.dispatch_thresholds(
-                dtype=None if dtype is None else dtype))
+            lambda: autotune.dispatch_thresholds(dtype=dtype))
 
     @property
     def thresholds(self) -> tuple:
@@ -999,8 +1087,7 @@ class MatFnEngine:
         key = jnp.dtype(dtype).name if dtype is not None else "any"
         return self._memoized(
             self._fastmm_cache, key,
-            lambda: autotune.fastmm_config(
-                dtype=None if dtype is None else dtype)[0])
+            lambda: autotune.fastmm_config(dtype=dtype)[0])
 
     def route_for(self, n: int, batch: int, dtype=None,
                   power=None) -> str:
@@ -1012,15 +1099,12 @@ class MatFnEngine:
         local routes. Huge-n buckets above the autotuned Strassen crossover
         (and not sharded-eligible) take ``fastmm`` — the only
         tolerance-bounded route; everything else is bit-identical to
-        per-matrix calls. Markov evolve buckets (``power`` slot
-        ``("evolve", steps, B)``) always take the fifth ``evolve`` route —
-        vector-matrix work has its own stream so a distribution sweep
-        never queues behind dense-square buckets; whether a big-B member
-        internally falls back to the dense path is the autotuned
-        ``markov`` threshold's call, not the router's.
+        per-matrix calls. A class whose op-table entry fixes its route
+        (markov evolve, named in the ``power`` slot) always takes it.
         """
-        if _is_evolve(power):
-            return "evolve"
+        fixed = _op_class(None, power)
+        if fixed is not None and fixed.route is not None:
+            return fixed.route
         cpu_max_n, sharded_min_n = self.thresholds_for(dtype)
         if self.mesh is not None and batch == 1 and n >= sharded_min_n:
             return "sharded"
@@ -1030,22 +1114,6 @@ class MatFnEngine:
             return "fastmm"
         return "chain"
 
-    @property
-    def _chain_backend(self) -> str:
-        return "pallas_chain_interpret" if self.interpret else "pallas_chain"
-
-    @property
-    def _fastmm_backend(self) -> str:
-        return "pallas_fastmm_interpret" if self.interpret else "pallas_fastmm"
-
-    def _route_backend(self, route: str) -> str:
-        """The core backend a local route runs its products on (the
-        evolve route's squarings; its members at n <= cpu_max_n and its
-        B-row products stay on the ``xla`` default)."""
-        return (self._chain_backend if route in ("chain", "evolve")
-                else self._fastmm_backend if route == "fastmm"
-                else "xla")
-
     def route_precision(self, route: str) -> str:
         """The dot precision a route's f32 products run at, by name: set
         per backend in one place (``core.matpow.precision_for``);
@@ -1053,10 +1121,7 @@ class MatFnEngine:
         The ``sharded`` route's collective chain keeps the default, and so
         do the Pallas kernels on bf16 operands
         (``kernels.matmul.kernel_precision``)."""
-        from repro.core.matpow import precision_for
-        precision = None if route == "sharded" \
-            else precision_for(self._route_backend(route))
-        return "default" if precision is None else precision.name.lower()
+        return self._routes[route].precision
 
     # -- executable cache --------------------------------------------------
     def _executable(self, op: str, route: str, padded_batch: int, n: int,
@@ -1066,98 +1131,39 @@ class MatFnEngine:
         # (the stream-count-invariance suite asserts exact accounting).
         # Building is cheap to hold a lock across — jax.jit only WRAPS
         # here; actual compilation happens on first call, on the stream.
-        with self._stats_lock:
-            return self._executable_locked(op, route, padded_batch, n,
-                                           dtype, power)
-
-    def _executable_locked(self, op: str, route: str, padded_batch: int,
-                           n: int, dtype: str, power: int):
         key = (op, route, padded_batch, n, dtype, power)
-        exe = self._executables.get(key)
-        if exe is not None:
-            self.stats["cache_hits"] += 1
-            return key, exe, False
-        if route == "sharded":
-            # The sharded chain drives its own jitted collective steps (one
-            # compiled step shared per mesh/shape): no outer jit and no
-            # stack, since the bucket is one matrix by construction. It
-            # answers a one-member tuple, like every bucket program.
-            from repro.core import distributed
-            mesh = self.mesh
-            if op == "markov":
-                # Mesh-resident steady state: the convergence loop runs on
-                # a ShardedMatmulChain (pad + 2-D sharding committed once,
-                # every squaring a donated collective step), the same
-                # structure as expm_sharded's loop.
-                from repro.core.markov import steady_state
-                chain = distributed.ShardedMatmulChain(
-                    n, jnp.dtype(dtype), mesh, donate=False)
-                exe = lambda x: (steady_state(x, validate=False,
-                                              chain=chain),)
-            elif op == "matpow":
-                exe = lambda x: (distributed.matpow_sharded(x, power,
-                                                            mesh),)
+        with self._stats_lock:
+            exe = self._executables.get(key)
+            if exe is not None:
+                self.stats["cache_hits"] += 1
+                return key, exe, False
+            entry = _op_class(op, power)
+            if route == "sharded":
+                # The sharded chain drives its own jitted collective steps
+                # (one compiled step shared per mesh/shape): no outer jit
+                # and no stack, since the bucket is one matrix by
+                # construction. It answers a one-member tuple, like every
+                # bucket program.
+                program = entry.sharded(self.mesh, n, jnp.dtype(dtype),
+                                        power)
+                exe = lambda x: (program(x),)
             else:
-                exe = lambda x: (distributed.expm_sharded(x, mesh),)
-        else:
-            exe = _bucket_program(self._per_stack(op, route, n, dtype,
-                                                  power))
-        self._executables[key] = exe
-        self.stats["compiles"] += 1
-        return key, exe, True
+                # One program per (op, route), whatever the padded batch.
+                small = n <= self.thresholds_for(dtype)[0]
+                exe = _bucket_program(entry.local(
+                    self._routes[route].backend, power, small))
+            self._executables[key] = exe
+            self.stats["compiles"] += 1
+            return key, exe, True
 
-    def _per_stack(self, op: str, route: str, n: int, dtype: str, power):
-        """What a local route's bucket program runs on the stacked bucket:
-        one program per (op, route), whatever the padded batch."""
-        backend = self._route_backend(route)
-        if op == "markov" and _is_evolve(power):
-            # The evolve route: lax.map of each (operand, dists) pair
-            # through the binary-decomposition vector-matrix chain, for
-            # the same reason as expm below: compile size stays O(1) in
-            # the bucket batch, and each member's big-B dense fallback
-            # decision (the autotuned ``markov`` threshold, resolved at
-            # trace time) is per-shape anyway.
-            from repro.core.markov import evolve_distributions
-            cpu_max_n, _ = self.thresholds_for(dtype)
-            per_member = functools.partial(
-                evolve_distributions, steps=power[1],
-                backend="xla" if n <= cpu_max_n else backend,
-                validate=False)
-            return lambda pairs: lax.map(
-                lambda pair: per_member(pair[1], pair[0]), pairs)
-        if op == "markov":
-            # Steady state: lax.map of the per-matrix convergence loop, so
-            # every bucket member keeps its OWN squaring count (a stacked
-            # loop would square everyone to the slowest mixer) and answers
-            # stay bit-identical to per-matrix steady_state calls.
-            from repro.core.markov import steady_state
-            per_matrix = functools.partial(steady_state, validate=False,
-                                           backend=backend)
-            return lambda stack: lax.map(per_matrix, stack)
-        if op == "matpow":
-            return functools.partial(batched_matpow, p=power, backend=backend)
-        # lax.map, NOT a stacked expm: the per-matrix 2-D program lowers
-        # identically inside the loop, so bucket answers stay bit-identical
-        # to per-matrix expm calls (a fused batched expm reassociates the
-        # elementwise Pade chain and drifts by ~1 ulp at B > 1), and each
-        # matrix keeps its own data-dependent squaring count instead of
-        # masking to the stack max. One program per bucket still amortizes
-        # dispatch across the batch.
-        per_matrix = functools.partial(_expm, backend=backend)
-        return lambda stack: lax.map(per_matrix, stack)
-
-    def _filler(self, n: int, dtype: str, power):
-        """The zero payload that pads a bucket of one class: an (n, n)
-        matrix, or for evolve an (operand, dists) pair. One per class,
-        shared by every padded slot; filler rows are never delivered."""
-        rows = power[2] if _is_evolve(power) else None
-        key = (n, dtype, rows)
+    def _filler(self, key: tuple):
+        """The zero payload that pads a bucket of class ``key``, shared by
+        every padded slot; filler rows are never delivered."""
         filler = self._fillers.get(key)
         if filler is None:
-            mat = jnp.zeros((n, n), dtype)
-            filler = mat if rows is None \
-                else (mat, jnp.zeros((rows, n), dtype))
-            filler = self._fillers.setdefault(key, filler)
+            op, n, dtype, power = key
+            filler = self._fillers.setdefault(
+                key, _op_class(op, power).filler(n, dtype, power))
         return filler
 
     def warm(self, op: str, n: int, dtype=jnp.float32, power: int = 1,
@@ -1184,12 +1190,15 @@ class MatFnEngine:
         classes are keyed on the (steps, B) pair, which warm has no
         argument for — their first bucket pays its own compile.
         """
+        entry = _REQUEST_CLASSES.get((op, False))
+        if entry is None:
+            raise ValueError(f"unknown op {op!r}; expected one of {OPS}")
         dtype = jnp.dtype(dtype)
         if batches is None:
             batches = sorted({bucket_batch(b, self.max_batch)
                               for b in range(1, self.max_batch + 1)})
-        power = power if op == "matpow" else -1
-        zeros = self._filler(n, dtype.name, power)
+        power = entry.slot(power, None)
+        zeros = self._filler((op, n, dtype.name, power))
         with self._cv:
             pool = self._pool
 
@@ -1202,7 +1211,7 @@ class MatFnEngine:
             operands = [zeros] * b
             if pool is not None:
                 stream = self._streams.stream_for(
-                    self.route_for(n, b, dtype.name))
+                    self.route_for(n, b, dtype.name, power))
                 jobs.append(pool.call(stream, functools.partial(run,
                                                                 operands)))
             else:
@@ -1255,7 +1264,7 @@ class MatFnEngine:
                                                power)
             members = list(operands)
             if bpad > b:
-                members += [self._filler(n, dtype, power)] * (bpad - b)
+                members += [self._filler((op, n, dtype, power))] * (bpad - b)
             assemble.tag(cold=fresh)
         t1 = clk()
         with execute:
@@ -1994,23 +2003,24 @@ class MatFnEngine:
                 }
 
     # -- convenience single-request API ------------------------------------
-    def matpow(self, a: jax.Array, power: int) -> jax.Array:
-        """Synchronous A^power through the engine (flushes the queue; in
-        daemon mode kicks the scheduler and waits on the future)."""
-        ticket = self.submit("matpow", a, power=power)
+    def _answer(self, op: str, operand, **request):
+        """Submit one request and return its answer: flush the queue, or
+        in daemon mode kick the request's bucket and wait on the future."""
+        ticket = self.submit(op, operand, **request)
         if isinstance(ticket, MatFnFuture):
             self.kick(ticket.bucket_key)
             return ticket.result()
         return self.flush()[ticket]
 
+    def matpow(self, a: jax.Array, power: int) -> jax.Array:
+        """Synchronous A^power through the engine (flushes the queue; in
+        daemon mode kicks the scheduler and waits on the future)."""
+        return self._answer("matpow", a, power=power)
+
     def expm(self, a: jax.Array) -> jax.Array:
         """Synchronous e^A through the engine (flushes the queue; in daemon
         mode kicks the scheduler and waits on the future)."""
-        ticket = self.submit("expm", a)
-        if isinstance(ticket, MatFnFuture):
-            self.kick(ticket.bucket_key)
-            return ticket.result()
-        return self.flush()[ticket]
+        return self._answer("expm", a)
 
     def steady_state(self, p: jax.Array):
         """Synchronous stationary distribution through the engine —
@@ -2018,11 +2028,7 @@ class MatFnEngine:
         (flushes the queue; in daemon mode kicks the scheduler and waits
         on the future). The engine does not validate stochasticity; gate
         with :func:`repro.core.markov.validate_stochastic` first."""
-        ticket = self.submit("markov", p)
-        if isinstance(ticket, MatFnFuture):
-            self.kick(ticket.bucket_key)
-            return ticket.result()
-        return self.flush()[ticket]
+        return self._answer("markov", p)
 
     def evolve(self, dists: jax.Array, p: jax.Array,
                steps: int) -> jax.Array:
@@ -2030,8 +2036,4 @@ class MatFnEngine:
         transitions under ``p`` through the engine's evolve route
         (flushes the queue; in daemon mode kicks the scheduler and waits
         on the future)."""
-        ticket = self.submit("markov", p, power=steps, dists=dists)
-        if isinstance(ticket, MatFnFuture):
-            self.kick(ticket.bucket_key)
-            return ticket.result()
-        return self.flush()[ticket]
+        return self._answer("markov", p, power=steps, dists=dists)

@@ -13,9 +13,9 @@ This module is the execution side of that split:
 
   * :class:`ExecutionStreams` — the frozen config: how many executor
     workers (streams) the engine runs and which dispatch route each one
-    serves. The default is one stream per route (``xla`` / ``chain`` /
-    ``sharded``); ``streams=1`` collapses every route onto a single
-    worker and reproduces the PR 6 serialized schedule exactly (the
+    serves. The default is one stream per route of
+    :data:`DEFAULT_ROUTES`; ``streams=1`` collapses every route onto a
+    single worker and reproduces the serialized schedule exactly (the
     stream-count-invariance property the test suite holds).
   * :class:`StreamPool` — the worker pool. The SCHEDULER thread keeps
     owning admission, bucketing, deadlines, and preemption; it hands each
@@ -64,11 +64,14 @@ from typing import Callable, List, Optional, Tuple
 __all__ = ["DEFAULT_ROUTES", "ExecutionStreams", "StreamPool",
            "StreamCrashed"]
 
-#: Dispatch routes the default stream layout covers, in stream order
-#: (mirrors ``repro.serve.matfn.ROUTES``; duplicated here because matfn
-#: imports this module). ``evolve`` is the markov distribution-evolution
-#: route — (B, n) vector-matrix chains, a different kernel shape from the
-#: dense-square routes, so it gets its own stream by default too.
+#: The engine's dispatch routes, in stream order; ``repro.serve.matfn``
+#: names this tuple ``ROUTES``. ``xla``/``chain``/``sharded`` are
+#: bit-identical to per-matrix calls of the same kernels; ``fastmm``
+#: (Strassen recursion above the autotuned crossover) is tolerance-bounded
+#: — see ``kernels.fastmm.error_budget``. ``evolve`` serves markov
+#: distribution-evolution buckets — (B, n) vector-matrix chains, a
+#: different kernel shape from the dense-square routes, so it gets its
+#: own stream by default too.
 DEFAULT_ROUTES = ("xla", "chain", "sharded", "fastmm", "evolve")
 
 

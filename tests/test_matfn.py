@@ -23,7 +23,9 @@ import jax.numpy as jnp
 from repro.core import (BatchedMatmulChain, batched_expm, batched_matpow,
                         expm, matpow_binary)
 from repro.kernels import autotune, ops
+from repro.serve import matfn, streams
 from repro.serve.matfn import MatFnEngine, MatFnRequest, bucket_batch
+from repro.serve.streams import ExecutionStreams
 from repro.serve.scheduler import ManualClock
 
 CHAIN = "pallas_chain_interpret"
@@ -453,6 +455,50 @@ class TestOneProgramPerBucket:
         assert eng.stats["compiles"] == sizes
         assert all(exe._cache_size() == 1
                    for exe in eng._executables.values())
+
+
+# The test kind that exercises each op-table entry.
+_ENTRY_KINDS = {"matpow": "matpow", "expm": "expm", "markov": "steady_state",
+                "evolve": "evolve"}
+
+
+class TestOpAndRouteTables:
+    """Each traffic class is one op-table entry and each route one
+    route-table row; the engine's behaviour follows from them."""
+
+    @pytest.mark.parametrize("entry", sorted(matfn._OP_TABLE))
+    def test_every_entry_warms_and_answers_on_its_route(self, entry):
+        kind = _ENTRY_KINDS[entry]
+        op, power, _ = _KINDS[kind]
+        eng = MatFnEngine(max_batch=4)
+        assert eng.warm(op, 8, power=power) == 3      # padded 1, 2 and 4
+        warmed = eng.stats["compiles"]
+        for b in (1, 3):                              # one member; padded
+            work = [_kind_payload(kind, 8, seed=400 + b + i)
+                    for i in range(b)]
+            for a, d in work:
+                _submit_kind(eng, kind, a, d)
+            got = eng.flush()
+            (row,) = eng.stats["last_flush"]
+            _op, route, bpad, n, dtype, slot = row["key"]
+            assert route == row["route"] == eng.route_for(n, b, dtype, slot)
+            assert bpad == row["padded_batch"] == bucket_batch(b)
+            for (a, d), g in zip(work, got, strict=True):
+                _assert_same(g, _kind_ref(kind, a, d))
+        # warm reaches every class but evolve's, which it has no dists for
+        fresh = 2 if matfn._OP_TABLE[entry].dists else 0
+        assert eng.stats["compiles"] == warmed + fresh
+
+    def test_route_names_agree(self):
+        eng = MatFnEngine()
+        snap = eng.stats()
+        default = ExecutionStreams()
+        assert matfn.ROUTES == streams.DEFAULT_ROUTES == default.routes
+        assert default.streams == len(matfn.ROUTES)
+        assert tuple(snap["routes"]) == matfn.ROUTES
+        assert tuple(snap["precision"]) == matfn.ROUTES
+        assert [eng.route_precision(r) for r in matfn.ROUTES] == \
+            list(snap["precision"].values())
 
 
 class TestHeterogeneousDispatch:
